@@ -40,6 +40,7 @@ from benchmarks import (
     table2_metrics,
     workflow_topologies,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = (
     ("table2", table2_metrics),
@@ -58,6 +59,7 @@ MODULES = (
 
 
 def main() -> None:
+    enable_compile_cache()
     # Each module resolves its own artifact dir via _smoke.out_dir(), so
     # smoke runs land in experiments/smoke/ from any entry point.
     failed = False

@@ -57,7 +57,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 DATA_AXIS = "data"     # batched sweep axis: fleet | workflow | capacity
 GRID_AXIS = "grid"     # scenario axis
@@ -118,7 +118,13 @@ def mesh_shape_3d(num_devices: int) -> tuple[int, int, int]:
 
 @functools.lru_cache(maxsize=None)
 def _cached_mesh(dd: int, dg: int, dp: int) -> Mesh:
-    return jax.make_mesh((dd, dg, dp), (DATA_AXIS, GRID_AXIS, POLICY_AXIS))
+    # Auto axes: the host-side strip of padded rows (``x[:b, :p, :w]``) is
+    # plain indexing, which explicit-sharding axes (``make_mesh``'s default)
+    # refuse without an ``out_sharding``.
+    return jax.make_mesh(
+        (dd, dg, dp), (DATA_AXIS, GRID_AXIS, POLICY_AXIS),
+        axis_types=(AxisType.Auto,) * 3,
+    )
 
 
 def grid_mesh(

@@ -78,7 +78,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import allocator as alloc
@@ -587,9 +586,9 @@ def _stream_grid_sharded(
         _stream_grid, config=config, names=names, batch_axis=batch_axis,
         num_policy_blocks=num_policy_blocks, block_size=block_size,
     )
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(arrivals, fleet, workflow, capacity, wspec, fspec)
 
 

@@ -36,7 +36,7 @@ def _kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    cache_len = len_ref[0]
+    cache_len = len_ref[pl.program_id(0)]
     pos = si * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
     valid = pos < jnp.minimum(cache_len, s_max)
     if window > 0:
@@ -89,22 +89,27 @@ def decode_attention(
     if cache_len.ndim == 0:
         cache_len = jnp.broadcast_to(cache_len, (b,))
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / (d ** 0.5), window=window, bk=bk, s_max=s_max),
+    # cache_len is scalar-prefetched into SMEM: Mosaic refuses a rank-1
+    # (1,) VMEM block, and the kernel only ever reads one scalar of it.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, kv, ns),
         in_specs=[
-            pl.BlockSpec((1,), lambda b_, kv_, si: (b_,)),
-            pl.BlockSpec((1, 1, group, d), lambda b_, kv_, si: (b_, kv_, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, kv_, si: (b_, kv_, si, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, kv_, si: (b_, kv_, si, 0)),
+            pl.BlockSpec((1, 1, group, d), lambda b_, kv_, si, lens: (b_, kv_, 0, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b_, kv_, si, lens: (b_, kv_, si, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b_, kv_, si, lens: (b_, kv_, si, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, d), lambda b_, kv_, si: (b_, kv_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, group, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, group, d), lambda b_, kv_, si, lens: (b_, kv_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, d), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=1.0 / (d ** 0.5), window=window, bk=bk, s_max=s_max),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv, group, d), q.dtype),
         interpret=interpret,
     )(cache_len, qt, kt, vt)
     return out.reshape(b, h, d)

@@ -3,9 +3,11 @@
 TPU-native adaptation of the SSD algorithm (arXiv:2405.21060 §6): the
 sequence is processed in chunks along the innermost grid dimension; the
 (P, N) recurrent state lives in VMEM scratch and persists across chunk
-steps, so HBM traffic is exactly one read of (x, dt, B, C) and one write
-of y — the quadratic intra-chunk work runs on the MXU as (Q,Q) and (Q,N)
-matmuls.
+steps, so the kernel reads (x, dt, B, C) once per head and writes y once
+— the quadratic intra-chunk work runs on the MXU as (Q,Q) and (Q,N)
+matmuls.  The wrapper moves x and y to and from a head-major layout, so
+that every block is tile-aligned; on a TPU ``chunk`` must be a multiple
+of 128 or the whole (padded) sequence.
 
 Grid: (batch, heads, num_chunks).  Validated against ``ref.ssd_chunked``
 and ``ref.ssd_naive`` in interpret mode by tests/test_kernels.py.
@@ -21,10 +23,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
+    a_ref, d_ref, x_ref, dtr_ref, dtc_ref, b_ref, c_ref, y_ref, hout_ref,
     h_ref,
     *, q,
 ):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -32,46 +35,50 @@ def _kernel(
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)           # (Q,)
+    x = x_ref[0, 0].astype(jnp.float32)                # (Q, P)
+    dt_row = dtr_ref[0, 0]                             # (1, Q)
+    dt_col = dtc_ref[0, 0]                             # (Q, 1)
     bm = b_ref[0].astype(jnp.float32)                  # (Q, N)
     cm = c_ref[0].astype(jnp.float32)                  # (Q, N)
-    a_h = a_ref[0]                                     # scalar A for this head
-    d_h = d_ref[0]
+    a_h = a_ref[hi]                                    # scalar A for this head
+    d_h = d_ref[hi]
 
-    a = a_h * dt                                       # (Q,)
-    a_cum = jnp.cumsum(a)                              # within-chunk
-    a_tot = a_cum[-1]
+    # Within-chunk cumsum of a = A·dt as masked reductions, once along
+    # lanes (column vector) and once along sublanes (row vector), so the
+    # kernel never transposes a vector.
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    tri = row >= col
+    a_row = a_h * dt_row                               # (1, Q)
+    a_col = a_h * dt_col                               # (Q, 1)
+    a_cum_col = jnp.sum(jnp.where(tri, a_row, 0.0), axis=1, keepdims=True)  # (Q, 1)
+    a_cum_row = jnp.sum(jnp.where(row <= col, a_col, 0.0), axis=0, keepdims=True)  # (1, Q)
+    a_tot = jnp.sum(a_row, axis=1, keepdims=True)      # (1, 1)
 
     # Intra-chunk: y_t += sum_{s<=t} exp(a_cum_t - a_cum_s) dt_s (C_t.B_s) x_s
-    seg = a_cum[:, None] - a_cum[None, :]              # (Q, Q)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= jax.lax.broadcasted_iota(
-        jnp.int32, (q, q), 1
-    )
-    gate = jnp.where(tri, jnp.exp(seg), 0.0)
+    gate = jnp.where(tri, jnp.exp(a_cum_col - a_cum_row), 0.0)  # (Q, Q)
     scores = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                                  # (Q, Q)
-    w = scores * gate * dt[None, :]
+    w = scores * gate * dt_row
     y = jax.lax.dot_general(
         w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                                  # (Q, P)
 
     # Inter-chunk: read out the carried state.
     h = h_ref[...]                                     # (P, N)
-    decay_in = jnp.exp(a_cum)[:, None]                 # (Q, 1)
     y += jax.lax.dot_general(
         cm, h, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * decay_in
+    ) * jnp.exp(a_cum_col)
 
     # State update: h <- exp(a_tot) h + sum_s exp(a_tot - a_cum_s) dt_s x_s B_s^T
-    wstate = (jnp.exp(a_tot - a_cum) * dt)[:, None]    # (Q, 1)
+    wstate = jnp.exp(a_tot - a_cum_col) * dt_col       # (Q, 1)
     upd = jax.lax.dot_general(
         x * wstate, bm, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                                  # (P, N)
     h_ref[...] = jnp.exp(a_tot) * h + upd
 
-    y_ref[0, :, 0, :] = (y + d_h * x).astype(y_ref.dtype)
+    y_ref[0, 0] = (y + d_h * x).astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _finish():
@@ -102,34 +109,43 @@ def ssd(x, dt, A, Bm, Cm, D, h0=None, *, chunk=64, interpret=False):
         return y[:, :s], hf
 
     nc = s // chunk
+    # Head-major layouts: every block's last two dims are (chunk, P),
+    # (1, chunk), (chunk, 1) or (chunk, N) -- full or tile-aligned, as
+    # Mosaic requires -- and the per-head A/D scalars live in SMEM.
+    xt = jnp.swapaxes(x, 1, 2)                                    # (B,H,S,P)
+    dtf = jnp.swapaxes(dt.astype(jnp.float32), 1, 2)              # (B,H,S)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     y, hout = pl.pallas_call(
         functools.partial(_kernel, q=chunk),
         grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, c_: (b_, c_, h_, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b_, h_, c_: (b_, c_, h_)),
-            pl.BlockSpec((1,), lambda b_, h_, c_: (h_,)),
+            smem,
+            smem,
+            pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c_: (b_, h_, c_, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b_, h_, c_: (b_, h_, 0, c_)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b_, h_, c_: (b_, h_, c_, 0)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, c_: (b_, c_, 0)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, c_: (b_, c_, 0)),
-            pl.BlockSpec((1,), lambda b_, h_, c_: (h_,)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, c_: (b_, c_, h_, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c_: (b_, h_, c_, 0)),
             pl.BlockSpec((1, 1, p, n), lambda b_, h_, c_: (b_, h_, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct(xt.shape, x.dtype),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
     )(
-        x,
-        dt.astype(jnp.float32),
         A.astype(jnp.float32),
-        Bm, Cm,
         D.astype(jnp.float32),
+        xt,
+        dtf[:, :, None, :],
+        dtf[..., None],
+        Bm, Cm,
     )
+    y = jnp.swapaxes(y, 1, 2)
     if h0 is not None:
         # Exact h0 correction: y_t += C_t (prod_{r<=t} a_r) h0 per head.
         af = A.astype(jnp.float32)

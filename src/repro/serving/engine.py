@@ -24,8 +24,10 @@ The TPU-native reading of "allocate GPU fraction g_i to agent i" (DESIGN.md
      pool stalls exactly as in the simulator; billing is warm-instance-ticks
      through the same ``billing_cost`` helper.
 
-Runs end-to-end on CPU with reduced configs (examples/serve_fleet.py) —
-the same engine the production launcher would drive per pod.
+The same engine runs reduced configs on the CPU (examples/serve_fleet.py,
+Pallas kernels replaced by their jnp references) and published-width
+configs on one TPU chip (chip_smoke.py, ``launch/serve.py::ONE_CHIP_FLEET``),
+where prefill and decode run the Pallas kernels.
 """
 from __future__ import annotations
 
@@ -73,6 +75,7 @@ class AgentRuntime:
     active: list = dataclasses.field(default_factory=list)  # per-slot Request|None
     caches: object = None
     pos: np.ndarray | None = None            # per-slot next position
+    _prefill_jit: Callable | None = None
     _decode_jit: Callable | None = None
 
     def __post_init__(self):
@@ -354,7 +357,10 @@ class FleetEngine:
             batch["frontend_embeds"] = jnp.zeros((1, fe, cfg.d_model), jnp.bfloat16)
         if cfg.arch_type == "encdec":
             batch["frontend_embeds"] = jnp.zeros((1, 64, cfg.d_model), jnp.bfloat16)
-        logits, caches1 = rt.api.prefill(rt.params, batch, rt.max_len)
+        if rt._prefill_jit is None:
+            ml = rt.max_len
+            rt._prefill_jit = jax.jit(lambda p, b: rt.api.prefill(p, b, ml))
+        logits, caches1 = rt._prefill_jit(rt.params, batch)
         tok = int(jnp.argmax(logits[0]))
         req.tokens_out.append(tok)
         if rt.caches is None:
