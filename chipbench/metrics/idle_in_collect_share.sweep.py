@@ -1,0 +1,10 @@
+"""Share of the traced window, in percent, in which the chip runs no op
+while the host is inside the program's ``repro:sweep.collect`` spans:
+device-to-host copies and building the result
+(``chipbench/program_spans.py``)."""
+
+from chipbench.program_spans import idle_share
+
+
+def read(run):
+    return idle_share(run, "collect")

@@ -428,7 +428,8 @@ def policy_stack(
     for i, name in enumerate(names):
         fn = get_policy(name)
         budget = g_total[i] if per_row_budget else g_total
-        rows.append(fn(t, lam_obs[i], lam_ema[i], queue[i], fleet, budget))
+        with jax.named_scope(name):
+            rows.append(fn(t, lam_obs[i], lam_ema[i], queue[i], fleet, budget))
     return jnp.stack(rows)
 
 

@@ -647,6 +647,12 @@ def simulate_stream_core(
     ``trace_metrics(simulate_core(...))`` within float tolerance
     (tests/test_streaming.py).
 
+    The step's stages carry ``jax.named_scope`` names, so a device trace
+    can attribute each op: ``synthesis``, ``policies`` (one nested scope
+    per policy name, ``allocator.policy_stack``), ``capacity``,
+    ``failures``, ``queue`` and ``accumulate``.  Scopes are op metadata
+    only: the computation, and every bit of its results, is unchanged.
+
     Returns ``(metrics (P, M), per-agent latency (P, N), per-agent
     throughput (P, N), per-agent queue (P, N))`` with P = len(policy_names)
     in name order (P/blocks rows of the current block when blocked) and
@@ -700,64 +706,72 @@ def simulate_stream_core(
             t > 0, alloc.ema_forecast(lam_ema, lam, config.ema_alpha), lam_ema
         )
         if elastic:
-            cstate, g_total_t, pending_t = cap_step(
-                rest[-1], capacity, t, lam.sum(axis=-1), lam_ema.sum(axis=-1),
-                queue.sum(axis=-1), config.g_total, config.num_gpus,
-            )
+            with jax.named_scope("capacity"):
+                cstate, g_total_t, pending_t = cap_step(
+                    rest[-1], capacity, t, lam.sum(axis=-1),
+                    lam_ema.sum(axis=-1), queue.sum(axis=-1), config.g_total,
+                    config.num_gpus,
+                )
             rest = rest[:-1] + (cstate,)
         else:
             g_total_t = config.g_total  # static python float: the pre-capacity program
             pending_t = jnp.zeros((p,), jnp.float32)
-        g = dispatch(t, lam, lam_ema, queue, g_total_t)
+        with jax.named_scope("policies"):
+            g = dispatch(t, lam, lam_ema, queue, g_total_t)
         if failing:
             # The chains are exogenous — one draw shared by every policy
             # row; only the per-policy bookkeeping carries a (P,) axis.
-            u_rev, u_down = fail_mod.failure_uniforms(failures, t, n)
-            phi, avail, rev_nxt, down_nxt = fail_mod.advance_failures(
-                failures, t, fstate.rev_on, fstate.down, u_rev, u_down
-            )
-            fail_t = jnp.maximum(
-                (phi > 0).astype(jnp.float32),
-                (((1.0 - avail) * fleet.active) > 0.5).any().astype(jnp.float32),
-            )
-            pre_q_tot = (queue * fleet.active).sum(-1)          # (P,)
-            onset = fail_t * (1.0 - fstate.fail_prev) * (1.0 - fstate.recovering)
-            q_mark = jnp.where(onset > 0, pre_q_tot, fstate.q_mark)
-            (served, new_queue, latency, completed, new_endo, mis,
-             new_retry_q, dropped, retried, viol) = _failure_queue_step(
-                queue, lam, g, fleet, config, route_eff, exit_frac,
-                failures, phi, avail, fstate.retry_q,
-            )
-            new_q_tot = (new_queue * fleet.active).sum(-1)
-            in_rec = (1.0 - fail_t) * jnp.maximum(fstate.fail_prev,
-                                                  fstate.recovering)
-            recovering = jnp.where(
-                fail_t > 0, fstate.recovering,
-                in_rec * (new_q_tot > q_mark).astype(jnp.float32),
-            )
-            fstate = fail_mod.FailureState(
-                rev_on=rev_nxt, down=down_nxt, fail_prev=fail_t,
-                recovering=recovering, q_mark=q_mark, retry_q=new_retry_q,
-            )
-            if elastic:
-                cstate = cap_mod.CapacityState(
-                    cstate.warm * (1.0 - phi), cstate.pipeline, cstate.idle_s
+            with jax.named_scope("failures"):
+                u_rev, u_down = fail_mod.failure_uniforms(failures, t, n)
+                phi, avail, rev_nxt, down_nxt = fail_mod.advance_failures(
+                    failures, t, fstate.rev_on, fstate.down, u_rev, u_down
                 )
-                rest = rest[:-1] + (cstate,)
+                fail_t = jnp.maximum(
+                    (phi > 0).astype(jnp.float32),
+                    (((1.0 - avail) * fleet.active) > 0.5).any().astype(jnp.float32),
+                )
+                pre_q_tot = (queue * fleet.active).sum(-1)          # (P,)
+                onset = fail_t * (1.0 - fstate.fail_prev) * (1.0 - fstate.recovering)
+                q_mark = jnp.where(onset > 0, pre_q_tot, fstate.q_mark)
+            with jax.named_scope("queue"):
+                (served, new_queue, latency, completed, new_endo, mis,
+                 new_retry_q, dropped, retried, viol) = _failure_queue_step(
+                    queue, lam, g, fleet, config, route_eff, exit_frac,
+                    failures, phi, avail, fstate.retry_q,
+                )
+            with jax.named_scope("failures"):
+                new_q_tot = (new_queue * fleet.active).sum(-1)
+                in_rec = (1.0 - fail_t) * jnp.maximum(fstate.fail_prev,
+                                                      fstate.recovering)
+                recovering = jnp.where(
+                    fail_t > 0, fstate.recovering,
+                    in_rec * (new_q_tot > q_mark).astype(jnp.float32),
+                )
+                fstate = fail_mod.FailureState(
+                    rev_on=rev_nxt, down=down_nxt, fail_prev=fail_t,
+                    recovering=recovering, q_mark=q_mark, retry_q=new_retry_q,
+                )
+                if elastic:
+                    cstate = cap_mod.CapacityState(
+                        cstate.warm * (1.0 - phi), cstate.pipeline, cstate.idle_s
+                    )
+                    rest = rest[:-1] + (cstate,)
         else:
-            served, new_queue, latency, completed, new_endo, mis = _queue_step(
-                queue, lam, g, fleet, config, route_eff, exit_frac
-            )
+            with jax.named_scope("queue"):
+                served, new_queue, latency, completed, new_endo, mis = _queue_step(
+                    queue, lam, g, fleet, config, route_eff, exit_frac
+                )
             dropped = retried = viol = in_rec = None
-        warm_t = jnp.broadcast_to(jnp.asarray(g_total_t, jnp.float32), (p,))
-        if failing:
-            # Billing excludes revoked instance-seconds (as in simulate_core).
-            warm_t = warm_t * (1.0 - phi)
-        acc = accumulate_metrics(
-            acc, fleet.active, g, served, new_queue, latency, completed,
-            warm_t, pending_t, misrouted=mis, dropped=dropped,
-            retried=retried, viol=viol, recovery=in_rec,
-        )
+        with jax.named_scope("accumulate"):
+            warm_t = jnp.broadcast_to(jnp.asarray(g_total_t, jnp.float32), (p,))
+            if failing:
+                # Billing excludes revoked instance-seconds (as in simulate_core).
+                warm_t = warm_t * (1.0 - phi)
+            acc = accumulate_metrics(
+                acc, fleet.active, g, served, new_queue, latency, completed,
+                warm_t, pending_t, misrouted=mis, dropped=dropped,
+                retried=retried, viol=viol, recovery=in_rec,
+            )
         out = (new_queue, lam_ema, new_endo, acc) + rest
         if failing:
             out = out + (fstate,)
@@ -767,9 +781,10 @@ def simulate_stream_core(
         # Single-level (block_size=1) scan body: per-step synthesis inline.
         if synth:
             t = inp
-            lam_row, wstate = workload_mod.workload_step(
-                workload_spec, carry[4], t, gen=gen_name
-            )
+            with jax.named_scope("synthesis"):
+                lam_row, wstate = workload_mod.workload_step(
+                    workload_spec, carry[4], t, gen=gen_name
+                )
             out = step_body(carry[:4] + carry[5:], t, lam_row * gate)
             return out[:4] + (wstate,) + out[4:], None
         t, lam_exo = inp
@@ -777,16 +792,18 @@ def simulate_stream_core(
 
     if synth:
         num_steps = workload_spec.num_steps
-        wstate0 = workload_mod.workload_init(workload_spec, gen=gen_name)
-        # EMA seed = the very row the scan body will synthesize at t=0
-        # (same step function, same fold — bit-identical to arrivals[0]
-        # of the materialized tensor, gated the same way).
-        lam0 = (
-            workload_mod.workload_step(
-                workload_spec, wstate0, jnp.asarray(0, jnp.int32), gen=gen_name
-            )[0]
-            * gate
-        )
+        with jax.named_scope("synthesis"):
+            wstate0 = workload_mod.workload_init(workload_spec, gen=gen_name)
+            # EMA seed = the very row the scan body will synthesize at t=0
+            # (same step function, same fold — bit-identical to arrivals[0]
+            # of the materialized tensor, gated the same way).
+            lam0 = (
+                workload_mod.workload_step(
+                    workload_spec, wstate0, jnp.asarray(0, jnp.int32),
+                    gen=gen_name,
+                )[0]
+                * gate
+            )
     else:
         num_steps = arrivals.shape[0]
         lam0 = arrivals[0]
@@ -855,9 +872,10 @@ def simulate_stream_core(
         def run_block(carry, ts_blk, lam_blk, scan_step, unroll):
             inner, wstate = split_wstate(carry)
             if synth:
-                lam_blk, wstate = workload_mod.step_block(
-                    workload_spec, wstate, ts_blk, gen=gen_name
-                )
+                with jax.named_scope("synthesis"):
+                    lam_blk, wstate = workload_mod.step_block(
+                        workload_spec, wstate, ts_blk, gen=gen_name
+                    )
                 lam_blk = lam_blk * gate
             inner, _ = jax.lax.scan(
                 scan_step, inner, (ts_blk, lam_blk), unroll=unroll

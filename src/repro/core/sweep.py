@@ -68,6 +68,15 @@ streaming metrics match it within float tolerance on all four grid types
 (tests/test_streaming.py).  ``return_arrays=True`` on any entry point
 skips the host transfer and returns raw device arrays — the benchmark
 timing surface.
+
+**Spans** (``core/spans.py``): every entry point runs as four
+back-to-back host spans under ``jax.profiler`` — ``repro:sweep.prepare``
+(validation, specs, stacking, policy ids, generator groups, sharded
+padding), ``repro:sweep.launch`` (the kernel call and the strip slices),
+``repro:sweep.wait`` (the device finishing the grid) and
+``repro:sweep.collect`` (host copies, ``SweepResult``) — sharing one
+``call`` number and carrying the grid's counts.  ``return_arrays=True``
+ends in ``launch``: it returns without a sync.
 """
 from __future__ import annotations
 
@@ -85,6 +94,7 @@ from repro.core import failures as fail_mod
 from repro.core import sharding
 from repro.core.sharding import grid_mesh  # re-export: the cached 2D mesh
 from repro.core import routing
+from repro.core import spans
 from repro.core import workload
 from repro.core.agents import Fleet, stack_fleets
 from repro.core.capacity import (
@@ -604,6 +614,7 @@ def _run_stream_sharded(
     fspec=None,
     policy_devices: int = 1,
     block_size: int = 1,
+    phases: spans.Phases | None = None,
 ):
     """Pad the sharded axes to mesh divisibility, run the sharded streaming
     kernel, strip the padding host-side.
@@ -638,12 +649,7 @@ def _run_stream_sharded(
     if batch_axis is None:
         w = arrivals.shape[0] if wspec is None else wspec.gen_id.shape[0]
         pad([(0, dd * dg)])
-        out = _stream_grid_sharded(
-            arrivals, fleet, workflow, capacity, wspec, fspec, mesh, config,
-            names, batch_axis, dp, block_size,
-        )
-        return tuple(x[:p, :w] for x in out)
-    if batch_axis == "fleet":
+    elif batch_axis == "fleet":
         b, w = (
             arrivals.shape[:2] if wspec is None else wspec.gen_id.shape[:2]
         )
@@ -664,11 +670,36 @@ def _run_stream_sharded(
         w = arrivals.shape[0] if wspec is None else wspec.gen_id.shape[0]
         pad([(0, dg)])
         capacity = sharding.pad_tree_axis(capacity, 0, dd)
+    keep = (() if batch_axis is None else (slice(b),)) + (slice(p), slice(w))
+    _launch(phases)
     out = _stream_grid_sharded(
         arrivals, fleet, workflow, capacity, wspec, fspec, mesh, config,
         names, batch_axis, dp, block_size,
     )
-    return tuple(x[:b, :p, :w] for x in out)
+    return tuple(x[keep] for x in out)
+
+
+def _launch(phases: spans.Phases | None) -> None:
+    """Mark the enqueue of the grid program: the end of ``prepare``."""
+    if phases is not None:
+        phases.enter("launch")
+
+
+def _grid_counts(arrivals, fleet, workflow, capacity, wspec, fspec, names,
+                 batch_axis) -> dict:
+    """The grid's size, recorded on the call's spans."""
+    batch = 1
+    if batch_axis is not None:
+        stacked = {"fleet": fleet, "workflow": workflow, "capacity": capacity,
+                   "failure": fspec}[batch_axis]
+        batch = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+    if wspec is None:
+        scenarios, steps = arrivals.shape[-3], arrivals.shape[-2]
+    else:
+        scenarios, steps = wspec.gen_id.shape[-1], wspec.num_steps
+    return {"batch": int(batch), "policies": len(names),
+            "scenarios": int(scenarios), "steps": int(steps),
+            "agents": int(fleet.active.shape[-1])}
 
 
 def _run_grid(
@@ -687,6 +718,7 @@ def _run_grid(
     wspec=None,
     fspec=None,
     block_size: int | None = None,
+    phases: spans.Phases | None = None,
 ):
     """Pick the kernel and placement for one sweep call: streaming by
     default — sharded over the ``("data", "grid", "policy")`` mesh whenever
@@ -699,7 +731,9 @@ def _run_grid(
     non-streaming call, so the trace oracle only ever sees tensors.
 
     Returns the kernel's device-array tuple — (metrics, per-lat, per-tput,
-    per-queue[, traces]).
+    per-queue[, traces]).  ``phases``, the entry point's spans, records
+    the grid's counts and moves from ``prepare`` to ``launch`` just before
+    the kernel is called.
     """
     streamed = (not keep_traces) if stream is None else bool(stream)
     if streamed and keep_traces:
@@ -713,6 +747,9 @@ def _run_grid(
             "in-scan synthesis runs inside the streaming kernel; "
             "materialize the specs for the trace oracle"
         )
+    if phases is not None:
+        phases.annotate(**_grid_counts(arrivals, fleet, workflow, capacity,
+                                       wspec, fspec, names, batch_axis))
     sharded = sharding.should_shard(shard)
     if streamed:
         # Resolved here — before any jit boundary — so the env default is
@@ -723,12 +760,13 @@ def _run_grid(
                 arrivals, fleet, workflow, capacity, config, names,
                 batch_axis, wspec=wspec, fspec=fspec,
                 policy_devices=sharding.policy_mesh_devices(shard),
-                block_size=bsz,
+                block_size=bsz, phases=phases,
             )
+        gen_groups = synth_gen_groups(wspec)
+        _launch(phases)
         return _stream_grid_jit(
             arrivals, fleet, workflow, capacity, wspec, fspec, config,
-            names, batch_axis, block_size=bsz,
-            gen_groups=synth_gen_groups(wspec),
+            names, batch_axis, block_size=bsz, gen_groups=gen_groups,
         )
     if sharded and batch_axis == "fleet":
         # The parity oracle keeps the pre-shard_map layout-hint path: pad
@@ -738,6 +776,7 @@ def _run_grid(
         # (traces included) host-side.
         f = arrivals.shape[0]
         fleet, arrivals = _shard_fleet_axis(fleet, arrivals)
+        _launch(phases)
         out = _grid_jit(
             pids, arrivals, fleet, workflow, capacity, fspec, config,
             reg_names, keep_traces, batch_axis,
@@ -745,6 +784,7 @@ def _run_grid(
         return tuple(
             jax.tree_util.tree_map(lambda x: x[:f], o) for o in out
         )
+    _launch(phases)
     return _grid_jit(
         pids, arrivals, fleet, workflow, capacity, fspec, config, reg_names,
         keep_traces, batch_axis,
@@ -851,6 +891,27 @@ def _resolve_failure_axis(failures, allow_batch: bool):
     return fail_mod.stack_failures(specs), tuple(s.name for s in specs)
 
 
+def _collect(phases: spans.Phases, out: tuple, keep_traces: bool,
+             return_arrays: bool, **fields) -> SweepResult | tuple:
+    """The end of every entry point: with ``return_arrays`` the kernel's
+    device arrays, unsynced; otherwise ``wait`` for the grid, then
+    ``collect`` it to the host as a ``SweepResult`` with ``fields``."""
+    if return_arrays:
+        return out
+    phases.enter("wait")
+    jax.block_until_ready(out)
+    phases.enter("collect")
+    metrics, per_lat, per_tput, per_q = (np.asarray(x) for x in out[:4])
+    return SweepResult(
+        metrics=metrics,
+        per_agent_latency=per_lat,
+        per_agent_throughput=per_tput,
+        per_agent_queue=per_q,
+        traces=out[4] if keep_traces else None,
+        **fields,
+    )
+
+
 def sweep(
     fleet: Fleet,
     scenarios: Sequence[Scenario],
@@ -908,38 +969,31 @@ def sweep(
     result carries ``failure_names``.  ``failures=None`` is bit-for-bit
     the pre-failure program; ``REPRO_FAILURES=0`` forces that path.
     """
-    fleet.validate()
-    if capacity is not None:
-        check_capacity(capacity, config.g_total, config.num_gpus)
-    fspec, failure_names = _resolve_failure_axis(failures, allow_batch=True)
-    batch_axis = None if failure_names is None else "failure"
-    reg_names = alloc.policy_names()
-    names = reg_names if policies is None else tuple(policies)
-    pids = jnp.asarray([alloc.policy_id(p) for p in names])
-    scen_names, arrivals, wspec = _prepare_scenarios(
-        scenarios, synthesize, _streamed(keep_traces, stream)
-    )  # (W, S, N) | stacked spec
+    with spans.Phases("sweep", "prepare", entry="sweep") as ph:
+        fleet.validate()
+        if capacity is not None:
+            check_capacity(capacity, config.g_total, config.num_gpus)
+        fspec, failure_names = _resolve_failure_axis(failures, allow_batch=True)
+        batch_axis = None if failure_names is None else "failure"
+        reg_names = alloc.policy_names()
+        names = reg_names if policies is None else tuple(policies)
+        pids = jnp.asarray([alloc.policy_id(p) for p in names])
+        scen_names, arrivals, wspec = _prepare_scenarios(
+            scenarios, synthesize, _streamed(keep_traces, stream)
+        )  # (W, S, N) | stacked spec
 
-    out = _run_grid(pids, arrivals, fleet, None, capacity, config,
-                       reg_names, names, keep_traces, stream, batch_axis,
-                       shard, wspec=wspec, fspec=fspec,
-                       block_size=block_size)
-    if return_arrays:
-        return out
-    metrics, per_lat, per_tput, per_q = (np.asarray(x) for x in out[:4])
-    traces = out[4] if keep_traces else None
-
-    return SweepResult(
-        policy_names=names,
-        scenario_names=scen_names,
-        metrics=metrics,
-        per_agent_latency=per_lat,
-        per_agent_throughput=per_tput,
-        config=config,
-        traces=traces,
-        per_agent_queue=per_q,
-        failure_names=failure_names,
-    )
+        out = _run_grid(
+            pids, arrivals, fleet, None, capacity, config, reg_names, names,
+            keep_traces, stream, batch_axis, shard, wspec=wspec, fspec=fspec,
+            block_size=block_size, phases=ph,
+        )
+        return _collect(
+            ph, out, keep_traces, return_arrays,
+            policy_names=names,
+            scenario_names=scen_names,
+            config=config,
+            failure_names=failure_names,
+        )
 
 
 def sweep_fleets(
@@ -985,78 +1039,74 @@ def sweep_fleets(
     mode); ``False`` materializes those same specs host-side (the
     synthesis parity arm, bit-identical to ``True`` by construction).
     """
-    fleets = list(fleets)
-    if not fleets:
-        raise ValueError("sweep_fleets needs at least one fleet")
-    for f in fleets:
-        f.validate()
-    if rate_vectors is None:
-        rate_vectors = [
-            workload.synthetic_rates(f.num_agents, seed=seed + i)
-            for i, f in enumerate(fleets)
-        ]
-    if len(rate_vectors) != len(fleets):
-        raise ValueError("need one rate vector per fleet")
-    for i, (f, r) in enumerate(zip(fleets, rate_vectors)):
-        width = np.asarray(r).shape[-1]
-        if width != f.num_agents:
-            raise ValueError(
-                f"rate vector {i} has {width} agents but fleet {i} has "
-                f"{f.num_agents}; a mismatch would silently zero real demand"
+    with spans.Phases("sweep", "prepare", entry="sweep_fleets") as ph:
+        fleets = list(fleets)
+        if not fleets:
+            raise ValueError("sweep_fleets needs at least one fleet")
+        for f in fleets:
+            f.validate()
+        if rate_vectors is None:
+            rate_vectors = [
+                workload.synthetic_rates(f.num_agents, seed=seed + i)
+                for i, f in enumerate(fleets)
+            ]
+        if len(rate_vectors) != len(fleets):
+            raise ValueError("need one rate vector per fleet")
+        for i, (f, r) in enumerate(zip(fleets, rate_vectors)):
+            width = np.asarray(r).shape[-1]
+            if width != f.num_agents:
+                raise ValueError(
+                    f"rate vector {i} has {width} agents but fleet {i} has "
+                    f"{f.num_agents}; a mismatch would silently zero real demand"
+                )
+        if fleet_names is None:
+            fleet_names = tuple(
+                f"fleet{i}_n{f.num_agents}" for i, f in enumerate(fleets)
             )
-    if fleet_names is None:
-        fleet_names = tuple(f"fleet{i}_n{f.num_agents}" for i, f in enumerate(fleets))
-    else:
-        fleet_names = tuple(fleet_names)
-
-    stacked = stack_fleets(fleets)
-    wspec = None
-    if synthesize is None:
-        scen_names, arrivals = fleet_scenario_library(
-            rate_vectors, stacked.num_agents, num_steps, seed
-        )  # (F, W, S, N_max)
-    else:
-        scen_names, spec_rows = workload.fleet_scenario_specs(
-            rate_vectors, stacked.num_agents, num_steps, seed
-        )
-        cols = [
-            workload.stack_specs(row, name=f"fleet{i}")
-            for i, row in enumerate(spec_rows)
-        ]
-        if (synthesize and _streamed(keep_traces, stream)
-                and workload.synth_env_enabled()):
-            arrivals = None
-            wspec = workload.stack_specs(cols, name="fleet_grid")
         else:
-            arrivals = jnp.stack([
-                jnp.stack([workload.materialize(s) for s in row])
-                for row in spec_rows
-            ])  # the parity arm: same step functions, host-scanned
+            fleet_names = tuple(fleet_names)
 
-    fspec, _ = _resolve_failure_axis(failures, allow_batch=False)
-    reg_names = alloc.policy_names()
-    names = reg_names if policies is None else tuple(policies)
-    pids = jnp.asarray([alloc.policy_id(p) for p in names])
+        stacked = stack_fleets(fleets)
+        wspec = None
+        if synthesize is None:
+            scen_names, arrivals = fleet_scenario_library(
+                rate_vectors, stacked.num_agents, num_steps, seed
+            )  # (F, W, S, N_max)
+        else:
+            scen_names, spec_rows = workload.fleet_scenario_specs(
+                rate_vectors, stacked.num_agents, num_steps, seed
+            )
+            cols = [
+                workload.stack_specs(row, name=f"fleet{i}")
+                for i, row in enumerate(spec_rows)
+            ]
+            if (synthesize and _streamed(keep_traces, stream)
+                    and workload.synth_env_enabled()):
+                arrivals = None
+                wspec = workload.stack_specs(cols, name="fleet_grid")
+            else:
+                arrivals = jnp.stack([
+                    jnp.stack([workload.materialize(s) for s in row])
+                    for row in spec_rows
+                ])  # the parity arm: same step functions, host-scanned
 
-    out = _run_grid(pids, arrivals, stacked, None, None, config,
-                       reg_names, names, keep_traces, stream, "fleet", shard,
-                       wspec=wspec, fspec=fspec, block_size=block_size)
-    if return_arrays:
-        return out
-    metrics, per_lat, per_tput, per_q = (np.asarray(x) for x in out[:4])
-    traces = out[4] if keep_traces else None
+        fspec, _ = _resolve_failure_axis(failures, allow_batch=False)
+        reg_names = alloc.policy_names()
+        names = reg_names if policies is None else tuple(policies)
+        pids = jnp.asarray([alloc.policy_id(p) for p in names])
 
-    return SweepResult(
-        policy_names=names,
-        scenario_names=scen_names,
-        metrics=metrics,
-        per_agent_latency=per_lat,
-        per_agent_throughput=per_tput,
-        config=config,
-        traces=traces,
-        fleet_names=fleet_names,
-        per_agent_queue=per_q,
-    )
+        out = _run_grid(
+            pids, arrivals, stacked, None, None, config, reg_names, names,
+            keep_traces, stream, "fleet", shard, wspec=wspec, fspec=fspec,
+            block_size=block_size, phases=ph,
+        )
+        return _collect(
+            ph, out, keep_traces, return_arrays,
+            policy_names=names,
+            scenario_names=scen_names,
+            config=config,
+            fleet_names=fleet_names,
+        )
 
 
 def workflow_scenario_library(
@@ -1108,55 +1158,48 @@ def sweep_workflows(
     ``grid`` (``shard=False`` / ``REPRO_SWEEP_SHARD=0`` force the
     single-device path).
     """
-    fleet.validate()
-    n = fleet.num_agents
-    if workflows is None:
-        workflows = workflow_scenario_library(n, seed=seed)
-    workflows = list(workflows)
-    if not workflows:
-        raise ValueError("sweep_workflows needs at least one workflow")
-    for wf in workflows:
-        routing.check_workflow(wf, n)
-    workflow_names = tuple(w.name for w in workflows)
-    if len(set(workflow_names)) != len(workflow_names):
-        raise ValueError(f"workflow names must be unique: {workflow_names}")
-    stacked_wf = stack_workflows(workflows)  # all widths == n after the check
+    with spans.Phases("sweep", "prepare", entry="sweep_workflows") as ph:
+        fleet.validate()
+        n = fleet.num_agents
+        if workflows is None:
+            workflows = workflow_scenario_library(n, seed=seed)
+        workflows = list(workflows)
+        if not workflows:
+            raise ValueError("sweep_workflows needs at least one workflow")
+        for wf in workflows:
+            routing.check_workflow(wf, n)
+        workflow_names = tuple(w.name for w in workflows)
+        if len(set(workflow_names)) != len(workflow_names):
+            raise ValueError(f"workflow names must be unique: {workflow_names}")
+        stacked_wf = stack_workflows(workflows)  # all widths == n after the check
 
-    if scenarios is None:
-        rates = workload.synthetic_rates(n, seed=seed)
-        scenarios = (
-            workload.scenario_specs(rates, num_steps, seed) if synthesize
-            else scenario_library(rates, num_steps, seed)
+        if scenarios is None:
+            rates = workload.synthetic_rates(n, seed=seed)
+            scenarios = (
+                workload.scenario_specs(rates, num_steps, seed) if synthesize
+                else scenario_library(rates, num_steps, seed)
+            )
+        scen_names, arrivals, wspec = _prepare_scenarios(
+            scenarios, synthesize, _streamed(keep_traces, stream)
+        )  # (W, S, N) | stacked spec
+
+        fspec, _ = _resolve_failure_axis(failures, allow_batch=False)
+        reg_names = alloc.policy_names()
+        names = reg_names if policies is None else tuple(policies)
+        pids = jnp.asarray([alloc.policy_id(p) for p in names])
+
+        out = _run_grid(
+            pids, arrivals, fleet, stacked_wf, None, config, reg_names, names,
+            keep_traces, stream, "workflow", shard, wspec=wspec, fspec=fspec,
+            block_size=block_size, phases=ph,
         )
-    scen_names, arrivals, wspec = _prepare_scenarios(
-        scenarios, synthesize, _streamed(keep_traces, stream)
-    )  # (W, S, N) | stacked spec
-
-    fspec, _ = _resolve_failure_axis(failures, allow_batch=False)
-    reg_names = alloc.policy_names()
-    names = reg_names if policies is None else tuple(policies)
-    pids = jnp.asarray([alloc.policy_id(p) for p in names])
-
-    out = _run_grid(pids, arrivals, fleet, stacked_wf, None, config,
-                       reg_names, names, keep_traces, stream, "workflow",
-                       shard, wspec=wspec, fspec=fspec,
-                       block_size=block_size)
-    if return_arrays:
-        return out
-    metrics, per_lat, per_tput, per_q = (np.asarray(x) for x in out[:4])
-    traces = out[4] if keep_traces else None
-
-    return SweepResult(
-        policy_names=names,
-        scenario_names=scen_names,
-        metrics=metrics,
-        per_agent_latency=per_lat,
-        per_agent_throughput=per_tput,
-        config=config,
-        traces=traces,
-        workflow_names=workflow_names,
-        per_agent_queue=per_q,
-    )
+        return _collect(
+            ph, out, keep_traces, return_arrays,
+            policy_names=names,
+            scenario_names=scen_names,
+            config=config,
+            workflow_names=workflow_names,
+        )
 
 
 def capacity_scenario_library(
@@ -1229,51 +1272,44 @@ def sweep_capacity(
     ``grid`` (``shard=False`` / ``REPRO_SWEEP_SHARD=0`` force the
     single-device path).
     """
-    fleet.validate()
-    if capacities is None:
-        capacities = capacity_scenario_library()
-    capacities = list(capacities)
-    if not capacities:
-        raise ValueError("sweep_capacity needs at least one capacity config")
-    for cp in capacities:
-        check_capacity(cp, config.g_total, config.num_gpus)
-    capacity_names = tuple(c.name for c in capacities)
-    if len(set(capacity_names)) != len(capacity_names):
-        raise ValueError(f"capacity names must be unique: {capacity_names}")
-    stacked_cap = stack_capacities(capacities)
+    with spans.Phases("sweep", "prepare", entry="sweep_capacity") as ph:
+        fleet.validate()
+        if capacities is None:
+            capacities = capacity_scenario_library()
+        capacities = list(capacities)
+        if not capacities:
+            raise ValueError("sweep_capacity needs at least one capacity config")
+        for cp in capacities:
+            check_capacity(cp, config.g_total, config.num_gpus)
+        capacity_names = tuple(c.name for c in capacities)
+        if len(set(capacity_names)) != len(capacity_names):
+            raise ValueError(f"capacity names must be unique: {capacity_names}")
+        stacked_cap = stack_capacities(capacities)
 
-    if scenarios is None:
-        rates = workload.synthetic_rates(fleet.num_agents, seed=seed)
-        scenarios = (
-            workload.scenario_specs(rates, num_steps, seed) if synthesize
-            else scenario_library(rates, num_steps, seed)
+        if scenarios is None:
+            rates = workload.synthetic_rates(fleet.num_agents, seed=seed)
+            scenarios = (
+                workload.scenario_specs(rates, num_steps, seed) if synthesize
+                else scenario_library(rates, num_steps, seed)
+            )
+        scen_names, arrivals, wspec = _prepare_scenarios(
+            scenarios, synthesize, _streamed(keep_traces, stream)
+        )  # (W, S, N) | stacked spec
+
+        fspec, _ = _resolve_failure_axis(failures, allow_batch=False)
+        reg_names = alloc.policy_names()
+        names = reg_names if policies is None else tuple(policies)
+        pids = jnp.asarray([alloc.policy_id(p) for p in names])
+
+        out = _run_grid(
+            pids, arrivals, fleet, None, stacked_cap, config, reg_names, names,
+            keep_traces, stream, "capacity", shard, wspec=wspec, fspec=fspec,
+            block_size=block_size, phases=ph,
         )
-    scen_names, arrivals, wspec = _prepare_scenarios(
-        scenarios, synthesize, _streamed(keep_traces, stream)
-    )  # (W, S, N) | stacked spec
-
-    fspec, _ = _resolve_failure_axis(failures, allow_batch=False)
-    reg_names = alloc.policy_names()
-    names = reg_names if policies is None else tuple(policies)
-    pids = jnp.asarray([alloc.policy_id(p) for p in names])
-
-    out = _run_grid(pids, arrivals, fleet, None, stacked_cap, config,
-                       reg_names, names, keep_traces, stream, "capacity",
-                       shard, wspec=wspec, fspec=fspec,
-                       block_size=block_size)
-    if return_arrays:
-        return out
-    metrics, per_lat, per_tput, per_q = (np.asarray(x) for x in out[:4])
-    traces = out[4] if keep_traces else None
-
-    return SweepResult(
-        policy_names=names,
-        scenario_names=scen_names,
-        metrics=metrics,
-        per_agent_latency=per_lat,
-        per_agent_throughput=per_tput,
-        config=config,
-        traces=traces,
-        capacity_names=capacity_names,
-        per_agent_queue=per_q,
-    )
+        return _collect(
+            ph, out, keep_traces, return_arrays,
+            policy_names=names,
+            scenario_names=scen_names,
+            config=config,
+            capacity_names=capacity_names,
+        )

@@ -57,6 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
+
 # Σ of the paper's §IV-A arrival rates (80+40+45+25 rps).
 PAPER_TOTAL_RATE = 190.0
 
@@ -391,22 +393,25 @@ def make_spec(
     (they never consume it).  ``num_steps`` must stay below the reserved
     init fold slot (2³¹−1) so step and init draws cannot collide.
     """
-    if len(knobs) > KNOB_SLOTS:
-        raise ValueError(f"at most {KNOB_SLOTS} knobs, got {len(knobs)}")
-    if not 0 < int(num_steps) < _INIT_FOLD:
-        raise ValueError(f"num_steps must be in (0, 2**31-1), got {num_steps}")
-    kv = np.zeros(KNOB_SLOTS, np.float32)
-    kv[: len(knobs)] = np.asarray(knobs, np.float32)
-    if key is None:
-        key = jax.random.key(0)
-    return WorkloadSpec(
-        gen_id=jnp.asarray(workload_id(gen), jnp.int32),
-        rates=jnp.asarray(rates, jnp.float32),
-        knobs=jnp.asarray(kv),
-        key_data=jax.random.key_data(key),
-        name=gen if name is None else name,
-        num_steps=int(num_steps),
-    )
+    with spans.span("workload.make_spec", gen=gen):
+        if len(knobs) > KNOB_SLOTS:
+            raise ValueError(f"at most {KNOB_SLOTS} knobs, got {len(knobs)}")
+        if not 0 < int(num_steps) < _INIT_FOLD:
+            raise ValueError(
+                f"num_steps must be in (0, 2**31-1), got {num_steps}"
+            )
+        kv = np.zeros(KNOB_SLOTS, np.float32)
+        kv[: len(knobs)] = np.asarray(knobs, np.float32)
+        if key is None:
+            key = jax.random.key(0)
+        return WorkloadSpec(
+            gen_id=jnp.asarray(workload_id(gen), jnp.int32),
+            rates=jnp.asarray(rates, jnp.float32),
+            knobs=jnp.asarray(kv),
+            key_data=jax.random.key_data(key),
+            name=gen if name is None else name,
+            num_steps=int(num_steps),
+        )
 
 
 def workload_init(spec: WorkloadSpec, gen: str | None = None) -> jnp.ndarray:
