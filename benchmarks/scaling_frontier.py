@@ -248,13 +248,11 @@ def _worker(cfg: dict) -> dict:
             )
             cells = f * len(names) * len(specs)
             if synth:
-                # Grouped static generator dispatch — the same fast path
-                # the public ``sweep`` entry point takes on one device.
                 stack = workload.stack_specs(specs)
                 compile_s, compiled = _bench.compile_probe(
                     sweep_mod._stream_grid_jit,
                     None, fleet, None, None, stack, None, config, names,
-                    None, 1, bsz, sweep_mod.synth_gen_groups(stack),
+                    None, 1, bsz,
                 )
                 fn = lambda: compiled(None, fleet, None, None, stack, None)
             else:
@@ -290,7 +288,6 @@ def _worker(cfg: dict) -> dict:
             cells = f * len(sub)
             fn = lambda: sweep_mod._stream_grid_jit(
                 None, fleet, None, None, stack, None, config, sub, None,
-                gen_groups=sweep_mod.synth_gen_groups(stack),
             )
             wall_us = _bench.time_device(fn, task["reps"])
             entries.append(_bench.timing_entry(

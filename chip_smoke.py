@@ -305,60 +305,30 @@ def _diff(got, want) -> str:
             f"of {d.size} differ, max |diff| {float(d.max())!r}, max relative {float(rel)!r}")
 
 
-def _switch_dispatch_grid(fleet, wspec, batch_axis) -> np.ndarray:
-    """The one-device streaming grid with the workload generators dispatched
-    through the vmapped ``lax.switch``, as the sharded body dispatches them
-    (``shard=False`` groups them statically instead)."""
-    from repro.core import allocator as alloc
-    from repro.core.simulator import SimConfig
-    from repro.core.sweep import _stream_grid_jit
-
-    return np.asarray(_stream_grid_jit(
-        None, fleet, None, None, wspec, None, SimConfig(), alloc.policy_names(),
-        batch_axis, block_size=1, gen_groups=None,
-    )[0])
-
-
 def check_sharded_sweeps(chips: int) -> None:
     """The sharded sweeps on the chips' mesh, in both workload arms.
 
     A sharded and an unsharded grid are two compilations, which on a TPU
     round differently (one f32 ulp in a few values), so both arms are held
     to tests/test_sharding.py's tolerance for such pairs, and bit-identity
-    is printed.  With materialized arrivals the reference is
-    ``shard=False``.  With in-scan synthesis the sharded body dispatches the
-    generators through a vmapped ``lax.switch`` while ``shard=False``
-    groups them statically; on a TPU that changes the rounding of the
-    trajectories, and threshold policies amplify it, so the reference is
-    the one-device grid with the same switch dispatch, and the difference
-    from ``shard=False`` is printed.
+    is printed.  The reference is ``shard=False`` in both arms: the
+    one-device grid runs the sharded path's per-device body, generator
+    dispatch included.
     """
     from repro.core import sharding, workload
-    from repro.core.agents import (
-        PAPER_ARRIVAL_RATES, paper_fleet, stack_fleets, synthetic_fleet,
-    )
+    from repro.core.agents import PAPER_ARRIVAL_RATES, paper_fleet, synthetic_fleet
     from repro.core.sweep import sweep, sweep_fleets
 
     if not sharding.should_shard():
         raise AssertionError("the sweeps would not shard on this host")
     specs = workload.scenario_specs(PAPER_ARRIVAL_RATES, num_steps=SWEEP_STEPS)
     fleets = [synthetic_fleet(FLEET_N, seed=i) for i in range(chips)]
-    rates = [workload.synthetic_rates(FLEET_N, seed=SEED + i) for i in range(chips)]
-    _, rows = workload.fleet_scenario_specs(rates, FLEET_N, FLEET_STEPS, SEED)
     grids = {
-        "sweep": (
-            lambda shard, synth: sweep(
-                paper_fleet(), specs, shard=shard, synthesize=synth).metrics,
-            lambda: _switch_dispatch_grid(
-                paper_fleet(), workload.stack_specs(specs), None),
-        ),
-        "sweep_fleets": (
-            lambda shard, synth: sweep_fleets(
-                fleets, num_steps=FLEET_STEPS, seed=SEED, synthesize=synth,
-                shard=shard).metrics,
-            lambda: _switch_dispatch_grid(stack_fleets(fleets), workload.stack_specs(
-                [workload.stack_specs(r) for r in rows]), "fleet"),
-        ),
+        "sweep": lambda shard, synth: sweep(
+            paper_fleet(), specs, shard=shard, synthesize=synth).metrics,
+        "sweep_fleets": lambda shard, synth: sweep_fleets(
+            fleets, num_steps=FLEET_STEPS, seed=SEED, synthesize=synth,
+            shard=shard).metrics,
     }
     # shard="3d" factors 4 chips with a policy axis of 1; the explicit
     # policy width of 2 splits the 9 policies (padded to 10) over two chips.
@@ -366,10 +336,9 @@ def check_sharded_sweeps(chips: int) -> None:
     mismatched = []
     for synth in (False, True):
         arm = "synthesized" if synth else "materialized"
-        for name, (grid, switch_grid) in grids.items():
+        for name, grid in grids.items():
             t0 = time.perf_counter()
             base = grid(False, synth)
-            ref = switch_grid() if synth else base
             log(f"{name} ({arm}): one device in {time.perf_counter() - t0:.1f} s")
             for layout, shard, dp in layouts:
                 if dp is None:
@@ -379,12 +348,9 @@ def check_sharded_sweeps(chips: int) -> None:
                 mesh = sharding.grid_mesh(policy_devices=sharding.policy_mesh_devices(shard))
                 t0 = time.perf_counter()
                 got = grid(shard, synth)
-                msg = (f"{name} ({arm}): {layout} on mesh {dict(mesh.shape)} in "
-                       f"{time.perf_counter() - t0:.1f} s; vs shard=False: {_diff(got, base)}")
-                if synth:
-                    msg += f"; vs one-device switch dispatch: {_diff(got, ref)}"
-                log(msg)
-                if not np.allclose(got, ref, rtol=1e-5, atol=1e-6):
+                log(f"{name} ({arm}): {layout} on mesh {dict(mesh.shape)} in "
+                    f"{time.perf_counter() - t0:.1f} s; vs shard=False: {_diff(got, base)}")
+                if not np.allclose(got, base, rtol=1e-5, atol=1e-6):
                     mismatched.append(f"{name} {arm} {layout}")
             os.environ.pop(sharding.POLICY_ENV, None)
     if mismatched:

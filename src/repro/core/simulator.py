@@ -579,7 +579,6 @@ def simulate_stream_core(
     num_policy_blocks: int = 1,
     policy_block: jnp.ndarray | None = None,
     block_size: int | None = None,
-    gen_name: str | None = None,
     failures: FailureSpec | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused streaming scan: every named policy's trajectory AND its metric
@@ -632,12 +631,12 @@ def simulate_stream_core(
     throughput, never results.  Peak memory per cell grows to O(B·N);
     both ends of the scan stay horizon-free.
 
-    ``gen_name`` statically names the spec's generator when the caller
-    knows it at trace time (the grouped-dispatch sweep path,
-    ``sweep.synth_gen_groups``): synthesis then calls that generator
-    directly instead of through ``lax.switch``, whose vmapped
-    evaluate-all-branches lowering makes every scenario column pay every
-    registered generator.  Results are bit-identical either way.
+    Synthesis dispatches the spec's generator by its traced ``gen_id``
+    (``workload_step``'s ``lax.switch``), so a grid vmapped over scenario
+    columns of different generators runs them all in this one scan.  Under
+    ``vmap`` the switch evaluates every registered generator for every
+    column; that costs less than a horizon loop per generator would (see
+    ``sweep._stream_grid``).
 
     Physics (``_queue_step``), EMA seeding, the autoscaler
     (``capacity_step``, vmapped over the policy rows — each policy's queue
@@ -783,7 +782,7 @@ def simulate_stream_core(
             t = inp
             with jax.named_scope("synthesis"):
                 lam_row, wstate = workload_mod.workload_step(
-                    workload_spec, carry[4], t, gen=gen_name
+                    workload_spec, carry[4], t
                 )
             out = step_body(carry[:4] + carry[5:], t, lam_row * gate)
             return out[:4] + (wstate,) + out[4:], None
@@ -793,14 +792,13 @@ def simulate_stream_core(
     if synth:
         num_steps = workload_spec.num_steps
         with jax.named_scope("synthesis"):
-            wstate0 = workload_mod.workload_init(workload_spec, gen=gen_name)
+            wstate0 = workload_mod.workload_init(workload_spec)
             # EMA seed = the very row the scan body will synthesize at t=0
             # (same step function, same fold — bit-identical to arrivals[0]
             # of the materialized tensor, gated the same way).
             lam0 = (
                 workload_mod.workload_step(
-                    workload_spec, wstate0, jnp.asarray(0, jnp.int32),
-                    gen=gen_name,
+                    workload_spec, wstate0, jnp.asarray(0, jnp.int32)
                 )[0]
                 * gate
             )
@@ -874,7 +872,7 @@ def simulate_stream_core(
             if synth:
                 with jax.named_scope("synthesis"):
                     lam_blk, wstate = workload_mod.step_block(
-                        workload_spec, wstate, ts_blk, gen=gen_name
+                        workload_spec, wstate, ts_blk
                     )
                 lam_blk = lam_blk * gate
             inner, _ = jax.lax.scan(

@@ -71,8 +71,8 @@ timing surface.
 
 **Spans** (``core/spans.py``): every entry point runs as four
 back-to-back host spans under ``jax.profiler`` — ``repro:sweep.prepare``
-(validation, specs, stacking, policy ids, generator groups, sharded
-padding), ``repro:sweep.launch`` (the kernel call and the strip slices),
+(validation, specs, stacking, policy ids, sharded padding),
+``repro:sweep.launch`` (the kernel call and the strip slices),
 ``repro:sweep.wait`` (the device finishing the grid) and
 ``repro:sweep.collect`` (host copies, ``SweepResult``) — sharing one
 ``call`` number and carrying the grid's counts.  ``return_arrays=True``
@@ -399,39 +399,6 @@ def _grid_jit(
     )
 
 
-def synth_gen_groups(wspec) -> tuple | None:
-    """Partition a stacked spec's scenario axis by generator, statically.
-
-    Returns ``((gen_name, (idx, ...)), ...)`` covering every scenario
-    column, or ``None`` when grouping does not apply (no spec, or a
-    fleet-batched stack whose scenario columns change generator across
-    fleets).  Must be called *outside* jit — it reads concrete ``gen_id``
-    values.
-
-    The payoff: ``_stream_grid`` vmaps each group separately with the
-    generator name passed statically, so synthesis dispatches directly
-    instead of through the vmapped ``lax.switch``, whose
-    evaluate-all-branches lowering makes every scenario column pay every
-    registered generator per step — the poisson sampler alone was measured
-    at ~93% of all-branches block synthesis cost while typically only one
-    column actually runs it.
-    """
-    if wspec is None:
-        return None
-    gids = np.asarray(wspec.gen_id)
-    if gids.ndim == 2:
-        # (F, W) fleet-batched stack: grouping needs one generator per
-        # scenario column across every fleet row.
-        if not (gids == gids[0]).all():
-            return None
-        gids = gids[0]
-    names = workload.workload_names()
-    groups: dict[int, list[int]] = {}
-    for i, gid in enumerate(gids.tolist()):
-        groups.setdefault(int(gid), []).append(i)
-    return tuple((names[gid], tuple(idx)) for gid, idx in groups.items())
-
-
 def _stream_grid(
     arrivals: jnp.ndarray | None,  # (W, S, N), or (F, W, S, N) when batch_axis="fleet"
     fleet: Fleet,            # leaves (N,), or (F, N) when batch_axis="fleet"
@@ -444,7 +411,6 @@ def _stream_grid(
     batch_axis: str | None = None,
     num_policy_blocks: int = 1,
     block_size: int = 1,
-    gen_groups: tuple | None = None,
 ):
     """The streaming (policy × scenario) grid kernel — the default for
     ``keep_traces=False`` sweeps.
@@ -465,28 +431,33 @@ def _stream_grid(
     3D mesh and computes only this device's policy block, selected by
     ``lax.axis_index("policy")`` (``allocator.policy_stack_blocks``).
 
-    ``gen_groups`` (static; build with ``synth_gen_groups``) partitions the
-    scenario axis by generator so each group's synthesis dispatches its
-    generator *directly* instead of through the vmapped ``lax.switch`` —
-    the single-device synth fast path.  The sharded placement keeps the
-    switch (``gen_groups=None``): ``shard_map`` needs one uniform program
-    whatever scenario columns land on a device.
+    Every scenario column runs in the same scan: synthesis dispatches the
+    generator through ``workload_step``'s ``lax.switch``, which under
+    ``vmap`` evaluates every registered generator for every column.  That
+    costs about 42 µs per step at N=1024 on a TPU v5e (85 µs of synthesis
+    against 44 when each column ran only its own generator).  The
+    alternative, one vmap per generator group, put one horizon loop per
+    group into the program (six for the 8-scenario library), each running
+    the whole policy stack every step at a sixth of the batch width.  On
+    one TPU v5e, 9 policies × 8 scenarios, one scan took 203 µs per step
+    against 568 for the grouped loops at N=1024 (4 fleets), and 58 against
+    223 at N=4.
 
     This function is deliberately unjitted: ``_stream_grid_jit`` wraps it
     for the single-device path and ``_stream_grid_sharded`` runs the exact
     same body per device block under ``shard_map`` — one kernel, two
-    placements, no way for the sharded math to drift.
+    placements, one dispatch, no way for the sharded math to drift.
     """
     block = (
         jax.lax.axis_index(sharding.POLICY_AXIS)
         if num_policy_blocks > 1 else None
     )
 
-    def cell(arr, fl, wf, cp, sp, fs, gen_name=None):
+    def cell(arr, fl, wf, cp, sp, fs):
         return simulate_stream_core(
             arr, fl, config, names, wf, cp, workload_spec=sp,
             num_policy_blocks=num_policy_blocks, policy_block=block,
-            block_size=block_size, gen_name=gen_name, failures=fs,
+            block_size=block_size, failures=fs,
         )
 
     a_ax = None if arrivals is None else 0
@@ -494,33 +465,9 @@ def _stream_grid(
 
     # out_axes=1: the per-cell policy axis stays leading, scenarios second,
     # matching the trace kernel's (…, P, W, ·) layout.
-    def over_scen(arr, fl, wf, cp, sp, fs):
-        if gen_groups is None or sp is None:
-            return jax.vmap(
-                cell, in_axes=(a_ax, None, None, None, s_ax, None),
-                out_axes=1,
-            )(arr, fl, wf, cp, sp, fs)
-        # Grouped static dispatch (``synth_gen_groups``): one vmap per
-        # generator group, each synthesizing through its generator
-        # directly — no vmapped ``lax.switch``, so no
-        # evaluate-all-branches blowup where every scenario column pays
-        # every registered sampler.  Outputs are reassembled in the
-        # caller's scenario order by a static inverse permutation;
-        # per-cell results are bit-identical to the switch path.
-        outs, order = [], []
-        for gname, idx in gen_groups:
-            sub = jax.tree_util.tree_map(
-                lambda x, i=np.asarray(idx): x[i], sp
-            )
-            outs.append(jax.vmap(
-                functools.partial(cell, gen_name=gname),
-                in_axes=(None, None, None, None, 0, None), out_axes=1,
-            )(None, fl, wf, cp, sub, fs))
-            order.extend(idx)
-        inv = np.argsort(np.asarray(order))
-        return jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs, axis=1)[:, inv], *outs
-        )
+    over_scen = jax.vmap(
+        cell, in_axes=(a_ax, None, None, None, s_ax, None), out_axes=1
+    )
 
     if batch_axis is None:
         return over_scen(arrivals, fleet, workflow, capacity, wspec, fspec)
@@ -539,7 +486,6 @@ _stream_grid_jit = functools.partial(
     jax.jit,
     static_argnames=(
         "config", "names", "batch_axis", "num_policy_blocks", "block_size",
-        "gen_groups",
     ),
 )(_stream_grid)
 
@@ -762,11 +708,10 @@ def _run_grid(
                 policy_devices=sharding.policy_mesh_devices(shard),
                 block_size=bsz, phases=phases,
             )
-        gen_groups = synth_gen_groups(wspec)
         _launch(phases)
         return _stream_grid_jit(
             arrivals, fleet, workflow, capacity, wspec, fspec, config,
-            names, batch_axis, block_size=bsz, gen_groups=gen_groups,
+            names, batch_axis, block_size=bsz,
         )
     if sharded and batch_axis == "fleet":
         # The parity oracle keeps the pre-shard_map layout-hint path: pad

@@ -414,20 +414,11 @@ def make_spec(
         )
 
 
-def workload_init(spec: WorkloadSpec, gen: str | None = None) -> jnp.ndarray:
-    """The generator's t=0 carry state, drawn from the reserved init fold.
-
-    ``gen`` names the generator *statically* when the caller knows it at
-    trace time (the grouped-dispatch sweep path): the ``lax.switch`` is
-    replaced by a direct call, so a vmapped caller does not lower every
-    registered branch.  The dispatched function is identical either way —
-    the draw is bit-for-bit the same.
-    """
+def workload_init(spec: WorkloadSpec) -> jnp.ndarray:
+    """The generator's t=0 carry state, drawn from the reserved init fold."""
     key_init = jax.random.fold_in(
         jax.random.wrap_key_data(spec.key_data), _INIT_FOLD
     )
-    if gen is not None:
-        return _WORKLOADS[gen].init(spec.rates, spec.knobs, key_init)
     return jax.lax.switch(
         spec.gen_id,
         [g.init for g in _WORKLOADS.values()],
@@ -439,22 +430,18 @@ def workload_step(
     spec: WorkloadSpec,
     state: jnp.ndarray,
     t: jnp.ndarray,
-    gen: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Step t's (N,) arrival row + next carry state, by switch dispatch.
 
     Pure in t: the key is ``fold_in(spec.key, t)``, so the same (spec,
     state, t) triple always yields the same draw — inside a scan, under
-    vmap, or called eagerly (the oracle's python loop).  A static ``gen``
-    bypasses the switch (see ``workload_init``): under ``vmap`` the switch
-    lowers to evaluate-all-branches-and-select, which makes every scenario
-    column pay every registered generator — the expensive ones (poisson's
-    iterative sampler) dominate whole sweeps.  Same function, same key,
-    same bits; only the dispatch differs.
+    vmap, or called eagerly (the oracle's python loop).  Under ``vmap`` the
+    switch lowers to evaluate-all-branches-and-select, so every scenario
+    column pays every registered generator.  The sweep keeps it anyway:
+    one scan over all columns is cheaper on the chip than one scan per
+    generator (``sweep._stream_grid``).
     """
     key_t = jax.random.fold_in(jax.random.wrap_key_data(spec.key_data), t)
-    if gen is not None:
-        return _WORKLOADS[gen].step(t, spec.rates, spec.knobs, state, key_t)
     return jax.lax.switch(
         spec.gen_id,
         [g.step for g in _WORKLOADS.values()],
@@ -478,7 +465,6 @@ def step_block(
     state: jnp.ndarray,
     ts: jnp.ndarray,
     unroll: int | None = None,
-    gen: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Synthesize a whole (B, N) arrival block in one call.
 
@@ -495,18 +481,12 @@ def step_block(
     B sequential ``workload_step`` calls (same draws per ``(spec, t)``,
     same recurrence ops, same state threading — the parity property in
     tests/test_workload_synthesis.py checks each generator).
-
-    A static ``gen`` skips the switch entirely (see ``workload_step`` — the
-    vmapped switch's evaluate-all-branches lowering is what makes every
-    scenario pay the poisson sampler); the grouped sweep path passes it.
     """
     b = ts.shape[0]
     u = min(b, MAX_UNROLL) if unroll is None else int(unroll)
     keys = jax.vmap(
         lambda t: jax.random.fold_in(jax.random.wrap_key_data(spec.key_data), t)
     )(ts)
-    if gen is not None:
-        return _WORKLOADS[gen].block(ts, spec.rates, spec.knobs, state, keys, u)
 
     def branch(g: _WorkloadGen):
         return lambda: g.block(ts, spec.rates, spec.knobs, state, keys, u)

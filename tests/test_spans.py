@@ -186,7 +186,6 @@ def _kernel_text(**kw) -> set[str]:
         None, fleet, None, kw.get("capacity"), wspec, kw.get("failures"),
         config=SimConfig(), names=alloc.policy_names(), batch_axis=None,
         block_size=kw.get("block_size", 1),
-        gen_groups=sweep_mod.synth_gen_groups(wspec),
     ))
 
 

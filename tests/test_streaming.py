@@ -25,6 +25,7 @@ import importlib
 
 from repro.core import allocator as alloc
 from repro.core import routing
+from repro.core import sharding
 from repro.core import workload
 from repro.core.agents import PAPER_ARRIVAL_RATES, paper_fleet, synthetic_fleet
 from repro.core.capacity import capacity_config
@@ -268,38 +269,90 @@ class TestTimeBlocking:
                     err_msg=f"{gen}/key={key}/S={num_steps}/B={b}/synth={synth}",
                 )
 
-    def test_gen_grouped_dispatch_bit_identical(self):
-        """The grouped static-dispatch synth path (``synth_gen_groups`` —
-        one vmap per generator group, no vmapped switch) must reproduce the
-        switch path bit-for-bit, across block sizes, on the full scenario
-        library (every registered generator plus a multi-member constant
-        group, in interleaved order)."""
-        n = 4
-        fleet = synthetic_fleet(n, seed=0)
-        specs = workload.scenario_specs(
-            workload.synthetic_rates(n, seed=0), num_steps=23, seed=0
+
+class TestOneScanGrid:
+    """The single-device grid runs every scenario column of the
+    8-scenario library, whatever its generator, in one horizon scan — the
+    same program the sharded path runs per device."""
+
+    N, STEPS = 4, 23
+
+    def _grid(self, entry, block_size):
+        """The entry point's single-device synthesized grid, its fleets,
+        and per fleet the scenario columns' specs as the entry builds them."""
+        if entry == "sweep":
+            fleets = [synthetic_fleet(self.N, seed=0)]
+            rows = [workload.scenario_specs(
+                workload.synthetic_rates(self.N, seed=0),
+                num_steps=self.STEPS, seed=0,
+            )]
+            res = sweep(fleets[0], rows[0], shard=False, synthesize=True,
+                        block_size=block_size)
+            parts = [(res.metrics, res.per_agent_latency,
+                      res.per_agent_throughput, res.per_agent_queue)]
+            return fleets, rows, parts
+        fleets = [synthetic_fleet(self.N, seed=i) for i in range(2)]
+        _, rows = workload.fleet_scenario_specs(
+            [workload.synthetic_rates(self.N, seed=i) for i in range(2)],
+            self.N, self.STEPS, 0,
         )
-        stack = workload.stack_specs(specs)
-        groups = sweep_mod.synth_gen_groups(stack)
-        # The library interleaves generators, so grouping really permutes.
-        assert groups is not None and len(groups) > 1
-        assert sorted(i for _, idx in groups for i in idx) == list(
-            range(len(specs))
+        res = sweep_fleets(fleets, num_steps=self.STEPS, seed=0,
+                           synthesize=True, shard=False, block_size=block_size)
+        parts = [(res.metrics[f], res.per_agent_latency[f],
+                  res.per_agent_throughput[f], res.per_agent_queue[f])
+                 for f in range(len(fleets))]
+        return fleets, rows, parts
+
+    @pytest.mark.parametrize("entry", ("sweep", "sweep_fleets"))
+    @pytest.mark.parametrize("block_size", (1, 4))
+    def test_grid_matches_per_column_runs(self, entry, block_size):
+        fleets, rows, parts = self._grid(entry, block_size)
+        core = jax.jit(simulate_stream_core, static_argnums=(2, 3),
+                       static_argnames=("block_size",))
+        names = alloc.policy_names()
+        for f, (fleet, row, grid) in enumerate(zip(fleets, rows, parts)):
+            stack = workload.stack_specs(row)
+            for w in range(len(row)):
+                col = jax.tree_util.tree_map(lambda x, w=w: x[w], stack)
+                want = core(None, fleet, SimConfig(), names,
+                            workload_spec=col, block_size=block_size)
+                for part, ref in zip(grid, want):
+                    np.testing.assert_array_equal(
+                        np.asarray(part)[:, w], np.asarray(ref),
+                        err_msg=f"{entry} fleet {f} {row[w].name} "
+                                f"B={block_size}",
+                    )
+
+    @staticmethod
+    def _horizon_loops(lowered, steps) -> int:
+        """Loops at the compiled program's top level that walk the horizon."""
+        text = lowered.compile().as_text()
+        entry = text[text.index("\nENTRY"):]
+        entry = entry[:entry.index("\n}")]
+        return sum(
+            f'"known_trip_count":{{"n":"{steps}"}}' in line
+            for line in entry.splitlines() if " while(" in line
         )
-        cfg = SimConfig()
-        for b in (1, 4):
-            base = sweep_mod._stream_grid_jit(
-                None, fleet, None, None, stack, None, cfg, self.NAMES, None,
-                1, b
-            )
-            grouped = sweep_mod._stream_grid_jit(
-                None, fleet, None, None, stack, None, cfg, self.NAMES, None,
-                1, b, gen_groups=groups,
-            )
-            for part, want in zip(grouped, base):
-                np.testing.assert_array_equal(
-                    np.asarray(part), np.asarray(want), err_msg=f"B={b}"
-                )
+
+    @pytest.mark.parametrize("entry", ("sweep", "sweep_fleets"))
+    def test_single_device_grid_holds_one_horizon_loop(self, entry, monkeypatch):
+        calls = []
+        real = sweep_mod._stream_grid_jit
+
+        def spy(*args, **kwargs):
+            calls.append((args, kwargs))
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(sweep_mod, "_stream_grid_jit", spy)
+        self._grid(entry, block_size=1)
+        (args, kwargs), = calls
+        grid, (config, names, batch_axis) = args[:6], args[6:9]
+        sharded = sweep_mod._stream_grid_sharded.lower(
+            *grid, mesh=sharding.grid_mesh(), config=config, names=names,
+            batch_axis=batch_axis, block_size=1,
+        )
+        assert self._horizon_loops(real.lower(*args, **kwargs), self.STEPS) == 1
+        assert self._horizon_loops(sharded, self.STEPS) == 1
 
 
 @hypothesis.given(

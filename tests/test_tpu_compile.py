@@ -23,7 +23,7 @@ from repro.core import allocator as alloc
 from repro.core import workload
 from repro.core.agents import stack_fleets, synthetic_fleet
 from repro.core.simulator import SimConfig
-from repro.core.sweep import _stream_grid_jit, synth_gen_groups
+from repro.core.sweep import _stream_grid_jit
 from repro.kernels.attention.decode_attention import decode_attention
 from repro.kernels.attention.flash_attention import flash_attention
 from repro.kernels.ssd.ssd_scan import ssd
@@ -119,6 +119,6 @@ def test_streaming_sweep_kernel_compiles_at_1024_agents(one_chip):
     compiled = _stream_grid_jit.lower(
         None, place(stack_fleets(fleets)), None, None, place(wspec), None,
         config=SimConfig(), names=alloc.policy_names(), batch_axis="fleet",
-        block_size=1, gen_groups=synth_gen_groups(wspec),
+        block_size=1,
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
