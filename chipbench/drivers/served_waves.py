@@ -15,14 +15,21 @@ holds whole ticks only.
 
 End to end: ``served_tokens_per_s`` (tokens generated by requests
 completed in the window, over the window's seconds) and
-``served_latency_p95_s`` (over every request completed in the window,
-from its submission to the end of the tick in which it finished).
+``served_latency_p90_s``, the 90th percentile of the latency of every
+request completed in the window, from its submission to the end of the
+tick in which it finished: a 30 s window completes two or three ticks of
+64 requests, and the 90th is the highest percentile with ten of them
+beyond it in both.  Every wave finishes in its tick, so this reads the
+slowest tick's length.
 
 ``correct``: once the window has closed and the engine is freed, requests
 drawn from the seed, for every agent, are run again by the float32
 reference (``chipbench/reference/models.py``) over their prompt and the
-tokens they were served; the number compared per agent is the widest gap
-by which a served token's logit lies below the reference's best.
+tokens they were served.  Per agent the traffic file's ``check.limit``
+names what is compared: the widest gap by which a served token's logit
+lies below the reference's best (``logit_gap``), or, where the program's
+widest gap and the control's do not separate, the mean gap per served
+token (``logit_gap_mean``).
 """
 from __future__ import annotations
 
@@ -179,16 +186,17 @@ def _check_positions(engine) -> None:
 
 def window(ctx, state, seconds):
     engine = state["engine"]
-    latencies, ticks = {}, 0
+    latencies, tick_s = {}, []
     t0 = time.perf_counter()
     while True:
+        t_tick = time.perf_counter()
         with span("submit"):
             _submit_waves(ctx, state)
         done = len(engine.completed)
         with span("engine.step"):
             engine.step()
         t_end = time.perf_counter()
-        ticks += 1
+        tick_s.append(t_end - t_tick)
         for r in engine.completed[done:]:
             latencies[r.id] = t_end - state["submitted"][r.id][1]
         _check_positions(engine)
@@ -197,14 +205,19 @@ def window(ctx, state, seconds):
     window_s = time.perf_counter() - t0
     finished = [state["submitted"][i][0] for i in latencies]
     tokens = sum(len(r.tokens_out) for r in finished)
-    log(f"served: {ticks} ticks, {len(finished)} requests, {tokens} tokens in {window_s!r} s")
+    lat = np.asarray(list(latencies.values()))
+    p90 = float(np.percentile(lat, 90))
+    ticks = len(tick_s)
+    log(f"served: {ticks} ticks, {len(finished)} requests, {tokens} tokens in {window_s!r} s; "
+        f"latency median {float(np.median(lat))!r} s, p90 {p90!r} s over {len(lat)} requests; "
+        f"tick seconds {tick_s}")
     state["finished"] = finished
     return {
         "window_s": window_s,
         "attempted": len(state["submitted"]),
         "failed": len(state["submitted"]) - len(finished),
         "metrics": {"served_tokens_per_s": tokens / window_s,
-                    "served_latency_p95_s": float(np.percentile(list(latencies.values()), 95))},
+                    "served_latency_p90_s": p90},
         "records": {"ticks": ticks, "waves": list(state["waves"]),
                     "models": {rt.name: served_models(ctx.config)[
                         ctx.config["served"]["backbones"][rt.name]]
@@ -235,9 +248,12 @@ def sample(ctx, state) -> dict:
 
 
 def reference_gaps(ctx, state, lower=None) -> dict:
-    """Per agent: the widest served-token gap of its sampled requests
-    (with ``lower``, the gap of the lower-precision reference's own
-    choices at the same positions)."""
+    """The numbers ``check`` can compare, per agent, over the served
+    tokens of its sampled requests: ``logit_gap.<agent>``, the widest gap
+    by which a served token's logit lies below the reference's best, and
+    ``logit_gap_mean.<agent>``, the mean gap per served token.  With
+    ``lower``, the gaps of the tokens the lower-precision reference puts
+    first at the same positions."""
     import jax
 
     w = seed_words(ctx.seed, 3)
@@ -247,7 +263,7 @@ def reference_gaps(ctx, state, lower=None) -> dict:
     for i, (name, decls) in enumerate(state["agents"]):
         reqs = picked[name]
         if not reqs:
-            out[name] = float("inf")
+            out[f"logit_gap.{name}"] = out[f"logit_gap_mean.{name}"] = float("inf")
             continue
         m = served_models(ctx.config)[served["backbones"][name]]
         params, = weights.make([decls], [weights.agent_key(w[0], i)])
@@ -255,9 +271,14 @@ def reference_gaps(ctx, state, lower=None) -> dict:
         toks = np.stack([np.asarray(r.tokens_out, np.int32) for r in reqs])
         gaps, dropped = ref_models.served_gaps(params, prompts, toks, m, lower)
         gaps = np.asarray(jax.device_get(gaps), np.float64)
-        out[name] = float(gaps.max())
+        out[f"logit_gap.{name}"] = float(gaps.max())
+        out[f"logit_gap_mean.{name}"] = float(gaps.mean())
+        worst = np.unravel_index(np.argmax(gaps), gaps.shape)
+        blocks = [float(gaps[:, j:j + 128].max()) for j in range(0, gaps.shape[1], 128)]
         log(f"served check {name}: {len(reqs)} requests x {toks.shape[1]} tokens, "
-            f"widest gap {out[name]!r}, per request {gaps.tolist()}, "
+            f"widest gap {gaps.max()!r} (request {int(worst[0])}, token {int(worst[1])}), "
+            f"per request {gaps.max(axis=1).tolist()}, mean {gaps.mean()!r}, "
+            f"tokens below the best {(gaps > 0).mean()!r}, widest per 128 tokens {blocks}, "
             f"prompt routings over capacity {float(dropped)!r}")
         del params
         gc.collect()
@@ -265,12 +286,18 @@ def reference_gaps(ctx, state, lower=None) -> dict:
 
 
 def check(ctx, state, result):
+    """The numbers the traffic file's ``check.limit`` names, each against
+    its limit; every agent has at least one."""
     limits = ctx.traffic["check"].get("limit")
     if limits is None:
         raise ValueError("the traffic file states no limits for logit_gap: set them from "
                          "the readings of chipbench/calibrate.py on the chip")
-    return [(f"logit_gap.{name}", value, float(limits[name]))
-            for name, value in reference_gaps(ctx, state).items()]
+    agents = {name for name, _ in state["agents"]}
+    unchecked = agents - {k.split(".", 1)[1] for k in limits}
+    if unchecked:
+        raise ValueError(f"no limit for agents {sorted(unchecked)}")
+    got = reference_gaps(ctx, state)
+    return [(name, got[name], float(limit)) for name, limit in limits.items()]
 
 
 def calls(records: dict) -> tuple[list, list]:

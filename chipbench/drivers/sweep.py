@@ -150,16 +150,22 @@ def setup(ctx):
 def window(ctx, state, seconds):
     tr = ctx.traffic
     call = state["call"]
-    grids = []
+    grids, ends = [], []
     t0 = time.perf_counter()
     while True:
         seed = state["base"] + 1 + len(grids)
         with span("grid"):
             res = call(seed)
         grids.append((seed, res.metrics))
-        if time.perf_counter() - t0 >= seconds:
+        ends.append(time.perf_counter())
+        if ends[-1] - t0 >= seconds:
             break
     window_s = time.perf_counter() - t0
+    each = np.diff([t0] + ends)
+    slow = [(i, float(s)) for i, s in enumerate(each) if s > 1.25 * np.median(each)]
+    log(f"sweep: seconds per grid min {each.min()!r}, quartiles "
+        f"{np.percentile(each, [25, 50, 75]).tolist()!r}, max {each.max()!r}; "
+        f"grids over 1.25 times the median (index, seconds) {slow}")
     steps = int(tr["num_steps"])
     policies, scenarios = state["names"]
     active = sum(int(np.asarray(f.active).sum()) for f in state["fleets"])

@@ -18,7 +18,10 @@ module of ``chipbench/drivers`` with four functions:
   when every value is at most its limit.
 
 A per-layer metric ``<name>`` is read by ``chipbench/metrics/<name>.py``,
-whose ``read(run)`` returns a number or ``None`` when it finds nothing.
+or, where there is no such file, by the reader of its first dotted part
+(``window_compiles.served`` by ``window_compiles.py``): a quantity split
+by the end-to-end metric its cells report can share one reader.
+``read(run)`` returns a number or ``None`` when it finds nothing.
 """
 from __future__ import annotations
 
@@ -145,6 +148,8 @@ def metric_entries(ctx: Ctx, kind: str) -> list[dict]:
 
 def read_metric(name: str, run: dict):
     path = HERE / "metrics" / f"{name}.py"
+    if not path.exists():
+        path = HERE / "metrics" / f"{name.split('.')[0]}.py"
     spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -237,7 +242,7 @@ def execute(ctx: Ctx, seconds: float, trace: bool, *, t0: float) -> dict:
         breakdown = summary["breakdown"]
         run = {"cell": ctx.cell, "config": ctx.config, "traffic": ctx.traffic,
                "window_s": result["window_s"], "records": result["records"],
-               "trace": traced, "devices": used,
+               "window_compiles": window_compiles, "trace": traced, "devices": used,
                "peaks": peaks_of(devices[0].device_kind)}
         for m in metric_entries(ctx, "per_layer"):
             value = read_metric(m["name"], run)

@@ -1,7 +1,7 @@
 """Share of the traced window, in percent, in which the chip runs no op
 while the host is inside the program's ``repro:sweep.prepare`` or
 ``repro:workload.make_spec`` spans: validation, spec building, stacking,
-generator groups, sharded padding (``chipbench/program_spans.py``)."""
+sharded padding (``chipbench/program_spans.py``)."""
 
 from chipbench.program_spans import idle_share
 

@@ -76,26 +76,34 @@ def merged_busy(ops, lo: float, hi: float) -> tuple[np.ndarray, np.ndarray]:
 
 def busy_before(starts, ends, x: np.ndarray) -> np.ndarray:
     """Busy time of the disjoint intervals before each point of ``x``."""
+    if not len(starts):
+        return np.zeros(len(x))
     done = np.concatenate([[0.0], np.cumsum(ends - starts)])
     k = np.searchsorted(starts, x, side="right")
     over = np.where(k > 0, np.maximum(ends[np.maximum(k - 1, 0)] - x, 0.0), 0.0)
     return done[k] - over
 
 
-def idle_by_label(busy, spans, lo: float, hi: float) -> dict[str, float]:
-    """Idle ns of one chip in [lo, hi] by the label of the innermost
-    program span over it; ``busy`` is ``merged_busy``'s pair."""
-    inner = sorted(((s, e, PHASE_OF.get(n, "unnamed")) for n, s, e in spans),
-                   key=lambda t: t[1] - t[0])
-    edges = np.unique(np.clip([lo, hi] + [x for s, e, _ in inner for x in (s, e)],
+def idle_by_label(busy, labelled, lo: float, hi: float, default: str) -> dict[str, float]:
+    """Idle ns of one chip in [lo, hi], cut at the edges of the
+    ``labelled`` spans (start, end, label) and summed by the label of the
+    first span in that list over each piece (``default`` under none);
+    ``busy`` is ``merged_busy``'s pair."""
+    edges = np.unique(np.clip([lo, hi] + [x for s, e, _ in labelled for x in (s, e)],
                               lo, hi))
     idle = np.diff(edges) - np.diff(busy_before(*busy, edges))
-    out = dict.fromkeys(LABELS, 0.0)
+    out = {}
     for a, b, ns in zip(edges[:-1], edges[1:], idle):
         mid = 0.5 * (a + b)
-        label = next((lab for s, e, lab in inner if s <= mid <= e), "unnamed")
-        out[label] += float(ns)
+        label = next((lab for s, e, lab in labelled if s <= mid <= e), default)
+        out[label] = out.get(label, 0.0) + float(ns)
     return out
+
+
+def innermost_first(spans) -> list[tuple[float, float, str]]:
+    """(start, end, label) of ``spans`` (label, start, end), shortest
+    first: the first that covers a point is the innermost there."""
+    return sorted(((s, e, n) for n, s, e in spans), key=lambda t: t[1] - t[0])
 
 
 def idle_shares(run) -> dict[str, float] | None:
@@ -113,10 +121,11 @@ def _idle_shares(trace, device_ids):
     planes = trace.events.device_planes(device_ids)
     if not spans or not planes or hi <= lo:
         return None
+    labelled = innermost_first((PHASE_OF[n], s, e) for n, s, e in spans)
     total = dict.fromkeys(LABELS, 0.0)
     for plane in planes:
         busy = merged_busy(trace.ops(plane), lo, hi)
-        for label, ns in idle_by_label(busy, spans, lo, hi).items():
+        for label, ns in idle_by_label(busy, labelled, lo, hi, "unnamed").items():
             total[label] += ns
     return {k: 100.0 * v / len(planes) / (hi - lo) for k, v in total.items()}
 

@@ -213,12 +213,12 @@ def forward(params, tokens, m: dict, prompt_len: int, at, lower=None):
 
 
 def served_gaps(params, prompts, served, m: dict, lower=None):
-    """For each sequence, the widest gap by which a served token's logit
-    lies below the reference's best, over every served token; with
-    ``lower``, the gap of the token the lower-precision pass puts first.
+    """For each served token, the gap by which its logit lies below the
+    reference's best at its position; with ``lower``, the gap of the
+    token the lower-precision pass puts first there.
 
     ``prompts`` (B, P) and ``served`` (B, T) int arrays of one length each.
-    Returns (gaps (B,), dropped routings)."""
+    Returns (gaps (B, T), dropped routings)."""
     prompts, served = jnp.asarray(prompts), jnp.asarray(served)
     p_len, t_len = prompts.shape[1], served.shape[1]
     tokens = jnp.concatenate([prompts, served[:, :-1]], axis=1)
@@ -233,7 +233,7 @@ def served_gaps(params, prompts, served, m: dict, lower=None):
             params, tokens, _frozen(m), p_len, tuple(at), lower)
         pick = jnp.argmax(low, axis=-1)
     chosen = jnp.take_along_axis(ref, pick[..., None], axis=-1)[..., 0]
-    return (best - chosen).max(axis=-1), dropped
+    return best - chosen, dropped
 
 
 class _frozen(dict):

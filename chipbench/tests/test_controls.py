@@ -98,8 +98,8 @@ def test_served_control_fails():
     with reduced_backbones():
         got = readings(ctx, harness.driver_of(ctx), 0.0)
     limits = ctx.traffic["check"]["limit"]
-    assert all(v <= limits[k] for k, v in got["program"].items()), got["program"]
-    assert any(v > limits[k] for k, v in got["control"].items()), got["control"]
+    assert all(got["program"][k] <= v for k, v in limits.items()), got["program"]
+    assert any(got["control"][k] > v for k, v in limits.items()), got["control"]
 
 
 @contextlib.contextmanager

@@ -127,10 +127,42 @@ def test_scopes_are_read_from_the_op_metadata(tmp_path):
         "/device:TPU:0": {"fusion.7": "jit(f)/policies/adaptive/add"}}
 
 
+def test_idle_gaps_are_named_by_the_innermost_program_span(tmp_path):
+    # Busy [20, 60): the gap [0, 20) lies under repro:sweep.prepare (and
+    # grid), [60, 100) under the benchmark's grid alone.
+    host = [("chipbench:window", 0, 100), ("chipbench:grid", 0, 100),
+            ("repro:sweep.prepare", 0, 20)]
+    run = _run(tmp_path, [("a", 20, 40)], host)
+    trace = run["trace"]
+    lo, hi = trace.window_bounds()
+    gaps = trace.idle_gaps("/device:TPU:0", lo, hi)
+    assert gaps == [["grid", 40e-9], ["repro:sweep.prepare", 20e-9]]
+    assert trace.summary([0])["breakdown"]["idle_gaps"] == gaps
+    # Nested program spans: make_spec [5, 8) inside prepare [0, 20); the
+    # piece under no span at all is named so.
+    run = _run(tmp_path, OPS, HOST)
+    gaps = dict(run["trace"].idle_gaps("/device:TPU:0", 0 + T0, 100 + T0))
+    assert gaps == {"repro:sweep.prepare": 7e-9, "repro:workload.make_spec": 3e-9,
+                    "repro:sweep.launch": 10e-9, "repro:sweep.wait": 20e-9,
+                    "repro:sweep.collect": 10e-9, "outside any span": 20e-9}
+
+
 def test_readers_find_nothing_in_a_program_without_spans_or_scopes(tmp_path):
     host = [h for h in HOST if not h[0].startswith(ps.PREFIX)]
     run = _run(tmp_path, OPS, host, modules=[("jit__stream_grid(1)", 0, 100)])
     assert all(harness.read_metric(name, run) is None for name in READERS)
+
+
+def test_every_per_layer_metric_has_a_reader_and_split_names_share_one():
+    bench = harness.load_json(harness.BENCH)
+    metrics = harness.HERE / "metrics"
+    for m in bench["per_layer"]:
+        name = m["name"]
+        assert ((metrics / f"{name}.py").exists()
+                or (metrics / f"{name.split('.')[0]}.py").exists()), name
+    run = {"window_compiles": {"jaxpr_to_mlir_module_duration": 3}}
+    assert harness.read_metric("window_compiles.sweep", run) == 3
+    assert harness.read_metric("window_compiles.served", run) == 3
 
 
 @pytest.fixture(scope="module")

@@ -13,8 +13,10 @@ so tracing costs the host little) and reads the ``.xplane.pb`` back with
   ``span(name)`` as ``chipbench:<name>``.
 
 Busy time is the union of the ``XLA Ops`` intervals of a chip; the idle
-share is one minus busy over the traced window.  A gap in that union is
-named by the innermost benchmark span that covers its midpoint.
+share is one minus busy over the traced window.  The idle time is cut at
+the edges of the host spans, and each piece is named by the innermost of
+the program's own spans over it (``repro:<name>``,
+``chipbench/program_spans.py``), or else by the innermost benchmark span.
 """
 from __future__ import annotations
 
@@ -22,6 +24,8 @@ import collections
 import glob
 import time
 from pathlib import Path
+
+from chipbench import program_spans as ps
 
 SPAN_PREFIX = "chipbench:"
 OPS_LINE = "XLA Ops"
@@ -160,22 +164,17 @@ class Traced:
         }
 
     def idle_gaps(self, plane: str, lo: float, hi: float) -> list:
-        """Idle seconds of ``plane`` inside [lo, hi], summed by the
-        innermost benchmark span covering each gap's midpoint, longest
-        first."""
-        busy = merge((max(s, lo), min(s + d, hi)) for _, s, d, _ in self.ops(plane)
-                     if s + d > lo and s < hi)
-        edges = [lo] + [x for iv in busy for x in iv] + [hi]
-        spans = sorted(((s, s + d, n) for n, s, d in self.events.host_spans()
-                        if n != "window"), key=lambda t: t[1] - t[0])
-        total = collections.Counter()
-        for a, b in zip(edges[0::2], edges[1::2]):
-            if b <= a:
-                continue
-            mid = 0.5 * (a + b)
-            label = next((n for s, e, n in spans if s <= mid <= e), "outside any span")
-            total[label] += (b - a) / 1e9
-        return [[n, v] for n, v in total.most_common()]
+        """Idle seconds of ``plane`` inside [lo, hi] by what the host was
+        doing: the innermost program span (``repro:<name>``) over each
+        piece, else the innermost benchmark span; longest first."""
+        program = ps.innermost_first((ps.PREFIX + n, s, e)
+                                     for n, s, e in ps.program_spans(self.events))
+        ours = ps.innermost_first((n, s, s + d) for n, s, d in self.events.host_spans()
+                                  if n != "window")
+        busy = ps.merged_busy(self.ops(plane), lo, hi)
+        gaps = ps.idle_by_label(busy, program + ours, lo, hi, "outside any span")
+        return [[n, v / 1e9] for n, v in sorted(gaps.items(), key=lambda kv: -kv[1])
+                if v > 0]
 
 
 class Tracer:
