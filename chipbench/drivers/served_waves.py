@@ -33,6 +33,7 @@ token (``logit_gap_mean``).
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import time
 
@@ -44,11 +45,6 @@ from chipbench.harness import log, seed_words
 from chipbench.reference import models as ref_models
 from chipbench.trace import span
 
-MODEL_KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim", "d_ff",
-              "vocab_size", "num_experts", "experts_per_token", "ssm_state_dim",
-              "ssm_head_dim", "ssm_expand", "ssm_conv_width", "ssm_chunk_size",
-              "frontend_tokens", "rope_theta", "norm_eps", "moe_capacity_factor")
-
 
 def mrope_counts(sections, half: int) -> list[int]:
     """Rotary frequencies per M-RoPE section, split as the program splits
@@ -59,14 +55,19 @@ def mrope_counts(sections, half: int) -> list[int]:
 
 
 def describe(mcfg) -> dict:
-    """The program's model configuration in the configuration file's terms."""
+    """The program's model configuration in the configuration file's terms:
+    every field of its ``ModelConfig`` (tuples as lists), ``arch`` for its
+    ``arch_type``, the M-RoPE sections as the program splits the rotary
+    frequencies, and no frontend positions without a vision frontend."""
     import inspect
 
     from repro.models import layers
 
-    out = {"arch": mcfg.arch_type, "tie_embeddings": mcfg.tie_embeddings}
-    for k in MODEL_KEYS:
-        out[k] = getattr(mcfg, k)
+    out = {}
+    for f in dataclasses.fields(mcfg):
+        v = getattr(mcfg, f.name)
+        out[f.name] = list(v) if isinstance(v, tuple) else v
+    out["arch"] = mcfg.arch_type
     if mcfg.mrope:
         sections = inspect.signature(layers.apply_mrope).parameters["sections"].default
         out["mrope_sections"] = mrope_counts(sections, mcfg.head_dim // 2)
@@ -90,7 +91,10 @@ def served_models(config: dict) -> dict:
 
 def model_configs(config: dict) -> dict:
     """Each backbone's program configuration, checked against the
-    configuration file: its published values with the listed departures."""
+    configuration file: every key of its entry but ``source``, published
+    values with the listed departures.  A key the program's configuration
+    lacks passes only where it is served ``null`` or ``false``: not
+    applied."""
     from repro.configs import get_config
 
     served = config["served"]
@@ -99,9 +103,10 @@ def model_configs(config: dict) -> dict:
     for arch in sorted(set(served["backbones"].values())):
         mcfg = get_config(arch)
         have, want = describe(mcfg), wanted[arch]
-        bad = {k: (want[k], have[k]) for k in want
-               if k in have and want[k] != have[k]
-               and not (k == "frontend_tokens" and want["arch"] != "dense")}
+        bad = {k: (v, have[k]) for k, v in want.items()
+               if k != "source" and k in have and v != have[k]}
+        bad.update({k: (v, "not in the program") for k, v in want.items()
+                    if k not in have and v is not None and v is not False})
         if bad:
             raise ValueError(f"{arch}: the program's configuration differs from the "
                              f"configuration file (wanted, found): {bad}")

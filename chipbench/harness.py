@@ -22,6 +22,10 @@ or, where there is no such file, by the reader of its first dotted part
 (``window_compiles.served`` by ``window_compiles.py``): a quantity split
 by the end-to-end metric its cells report can share one reader.
 ``read(run)`` returns a number or ``None`` when it finds nothing.
+
+A served backbone's ``arch`` names its plain reference and its operation
+and byte counts, ``chipbench/reference/archs/<arch>.py`` and
+``chipbench/counts/archs/<arch>.py`` (``arch_module``).
 """
 from __future__ import annotations
 
@@ -86,6 +90,22 @@ def load_cell(name: str, bench: dict | None = None) -> Ctx:
 
 def driver_of(ctx: Ctx):
     return importlib.import_module(f"chipbench.drivers.{ctx.traffic['driver']}")
+
+
+def arch_module(package: str, arch: str):
+    """``chipbench/<package>/archs/<arch>.py``: what ``package``
+    (``reference`` or ``counts``) knows of one served architecture, named
+    by the ``arch`` of a backbone's entry in the configuration file."""
+    if not arch.isidentifier():
+        raise ValueError(f"served architecture {arch!r} is not a module name")
+    name = f"chipbench.{package}.archs.{arch}"
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise ValueError(f"no {package} for served architecture {arch!r}: "
+                         f"chipbench/{package}/archs/{arch}.py is missing") from None
 
 
 def require_chips(chips: int):

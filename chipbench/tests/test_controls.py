@@ -224,9 +224,9 @@ def test_served_fault_is_caught(fault):
 
 
 def test_control_rounding_is_lower_precision():
-    from chipbench.reference import models
+    from chipbench.reference import layers
 
     x = jnp.asarray(np.random.default_rng(0).standard_normal(4096), jnp.float32)
-    err = jnp.abs(models._round(x, ml_dtypes.float8_e4m3fn) - x).max()
+    err = jnp.abs(layers._round(x, ml_dtypes.float8_e4m3fn) - x).max()
     bf16 = jnp.abs(x.astype(jnp.bfloat16).astype(jnp.float32) - x).max()
     assert float(err) > 4 * float(bf16)
