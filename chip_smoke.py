@@ -114,7 +114,7 @@ def check_kernels() -> dict:
 
     b = 4
     q = _bf16(k[3], (b, h, d))
-    kc, vc = _bf16(k[4], (b, MAX_LEN, kv, d)), _bf16(k[5], (b, MAX_LEN, kv, d))
+    kc, vc = _bf16(k[4], (b, kv, d, MAX_LEN)), _bf16(k[5], (b, kv, d, MAX_LEN))
     lens = jnp.asarray([1, 100, 257, MAX_LEN], jnp.int32)
     got = attn_ops.decode_attention(q, kc, vc, lens, impl="pallas")
     with jax.default_matmul_precision("float32"):

@@ -16,8 +16,12 @@ import jax
 from repro.kernels.attention import ref
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "pallas" if _on_tpu() else "ref"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_offset", "impl"))
@@ -33,7 +37,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0, impl=None):
         return ref.mha(q, k, v, causal=causal, window=window, q_offset=q_offset)
     from repro.kernels.attention import flash_attention as fa
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = not _on_tpu()
     return fa.flash_attention(
         q, k, v, causal=causal, window=window, q_offset=q_offset, interpret=interpret
     )
@@ -41,13 +45,13 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0, impl=None):
 
 @functools.partial(jax.jit, static_argnames=("window", "impl"))
 def decode_attention(q, k_cache, v_cache, cache_len, *, window=0, impl=None):
-    """(B,H,D) x (B,S_max,KV,D)^2 -> (B,H,D), masked to `cache_len` entries."""
+    """(B,H,D) x (B,KV,D,S_max)^2 -> (B,H,D), masked to `cache_len` entries."""
     impl = impl or _default_impl()
     if impl == "ref":
         return ref.decode_gqa(q, k_cache, v_cache, cache_len, window=window)
     from repro.kernels.attention import decode_attention as da
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = not _on_tpu()
     return da.decode_attention(
         q, k_cache, v_cache, cache_len, window=window, interpret=interpret
     )

@@ -5,7 +5,7 @@ Shapes (GQA throughout):
   k, v:   (B, S_kv, KV, D)   with H % KV == 0
 Decode:
   q:      (B, H, D)          one new token
-  cache:  (B, S_max, KV, D)
+  cache:  (B, KV, D, S_max)  the decode cache layout, positions minor
 
 ``window > 0`` = sliding-window causal attention (Mixtral / local attention
 in RecurrentGemma).  ``causal=False, window=0`` = bidirectional (encoder) or
@@ -137,17 +137,17 @@ def decode_gqa(
 ) -> jnp.ndarray:
     """One-token decode attention against a (possibly rolling) KV cache.
 
-    q: (B, H, D); caches: (B, S_max, KV, D); cache_len: () or (B,) number of
+    q: (B, H, D); caches: (B, KV, D, S_max); cache_len: () or (B,) number of
     valid entries.  For rolling (sliding-window) caches the valid region is
     the whole buffer once cache_len >= S_max; masking uses entry validity
     only — relative order is irrelevant to softmax(QK^T)V.  No KV
     repetition (see ``mha``).
     """
     b, h, d = q.shape
-    s_max, kv = k_cache.shape[1], k_cache.shape[2]
+    kv, s_max = k_cache.shape[1], k_cache.shape[3]
     g = h // kv
     qg = q.reshape(b, kv, g, d)
-    logits = jnp.einsum("bngd,bknd->bngk", qg, k_cache).astype(jnp.float32)
+    logits = jnp.einsum("bngd,bndk->bngk", qg, k_cache).astype(jnp.float32)
     logits *= 1.0 / jnp.sqrt(d).astype(jnp.float32)
     cache_len = jnp.asarray(cache_len)
     if cache_len.ndim == 0:
@@ -159,5 +159,5 @@ def decode_gqa(
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)
-    out = jnp.einsum("bngk,bknd->bngd", probs.astype(v_cache.dtype), v_cache)
+    out = jnp.einsum("bngk,bndk->bngd", probs.astype(v_cache.dtype), v_cache)
     return out.reshape(b, h, d).astype(q.dtype)

@@ -95,17 +95,33 @@ class KVCacheSpec:
 
 
 def kv_cache_decls(cfg: ModelConfig, batch: int, spec: KVCacheSpec):
+    """K and V stored as the decode kernel reads them: (B, KV, Dh, S_cache),
+    positions in the lanes, dense for every head_dim (``seed_kv_cache``)."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim
-    axes = ("cache_batch", "kv_seq", "kv_heads", None)
+    axes = ("cache_batch", "kv_heads", None, "kv_seq")
     return {
-        "k": decl((batch, spec.size, kv, hd), axes, init="zeros"),
-        "v": decl((batch, spec.size, kv, hd), axes, init="zeros"),
+        "k": decl((batch, kv, hd, spec.size), axes, init="zeros"),
+        "v": decl((batch, kv, hd, spec.size), axes, init="zeros"),
     }
+
+
+def seed_kv_cache(x: jnp.ndarray, spec: KVCacheSpec, s: int) -> jnp.ndarray:
+    """Prefill's keys or values (..., B, S, KV, Dh) over `s` tokens -> the
+    decode cache (..., B, KV, Dh, spec.size): transposed once a request,
+    so that no decode step relayouts the cache."""
+    if s >= spec.size:
+        x = x[..., s - spec.size : s, :, :]
+        if spec.window > 0:  # rolling layout: token t lives at t % size
+            x = jnp.roll(x, s % spec.size, axis=-3)
+    else:
+        pad = [(0, 0)] * (x.ndim - 3) + [(0, spec.size - s), (0, 0), (0, 0)]
+        x = jnp.pad(x, pad)
+    return jnp.moveaxis(x, -3, -1)
 
 
 def decode_self_attention(
     x: jnp.ndarray,            # (B, 1, D)
-    cache,                     # {"k","v"}: (B, S_cache, KV, Dh)
+    cache,                     # {"k","v"}: (B, KV, Dh, S_cache)
     p,
     cfg: ModelConfig,
     pos: jnp.ndarray,          # () current token index
@@ -140,7 +156,7 @@ def decode_self_attention(
 
 
 def _update_cache(cache: jnp.ndarray, new: jnp.ndarray, slot: jnp.ndarray) -> jnp.ndarray:
-    """cache (B,S,KV,Dh) <- new (B,KV,Dh) at position `slot`."""
+    """cache (B,KV,Dh,S) <- new (B,KV,Dh) as the column at position `slot`."""
     return jax.lax.dynamic_update_slice(
-        cache, new[:, None].astype(cache.dtype), (0, slot, 0, 0)
+        cache, new[..., None].astype(cache.dtype), (0, 0, 0, slot)
     )

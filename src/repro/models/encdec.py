@@ -146,12 +146,13 @@ def prefill(params, batch, cfg: ModelConfig, max_len: int):
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x, caches_seq = _decode_stack(params, x, enc_out, cfg, positions, collect_cache=True)
     (k_seq, v_seq), (ck, cv) = caches_seq
-    pad = max_len - s
-    k_cache = jnp.pad(k_seq, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    v_cache = jnp.pad(v_seq, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+    spec = KVCacheSpec(size=max_len)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(x[:, -1:], params["embed"])[:, 0]
-    caches = {"self": {"k": k_cache, "v": v_cache}, "cross_k": ck, "cross_v": cv}
+    caches = {
+        "self": {"k": attn.seed_kv_cache(k_seq, spec, s), "v": attn.seed_kv_cache(v_seq, spec, s)},
+        "cross_k": ck, "cross_v": cv,
+    }
     return logits, caches
 
 

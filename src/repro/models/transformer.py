@@ -256,18 +256,7 @@ def _seed_to_cache(kind: str, seed, cfg: ModelConfig, spec: KVCacheSpec, s: int)
     """Convert a full-sequence cache seed into the decode cache layout."""
     if kind in ("attn_mlp", "attn_moe"):
         k, v = seed  # (..., B, S, KV, Dh); leading layer axis when stacked
-
-        def to_cache(x):
-            if s >= spec.size:
-                x = x[..., s - spec.size : s, :, :]
-                if spec.window > 0:  # rolling layout: token t lives at t % size
-                    x = jnp.roll(x, s % spec.size, axis=-3)
-            else:
-                pad = [(0, 0)] * (x.ndim - 3) + [(0, spec.size - s), (0, 0), (0, 0)]
-                x = jnp.pad(x, pad)
-            return x
-
-        return {"k": to_cache(k), "v": to_cache(v)}
+        return {"k": attn.seed_kv_cache(k, spec, s), "v": attn.seed_kv_cache(v, spec, s)}
     conv_tail, h = seed
     return {"conv": conv_tail, "h": h.astype(jnp.float32)}
 

@@ -6,10 +6,11 @@ import numpy as np
 import pytest
 
 from repro.kernels.attention import ref as aref
-from repro.kernels.attention.decode_attention import decode_attention
+from repro.kernels.attention.decode_attention import _block_len, decode_attention
 from repro.kernels.attention.flash_attention import flash_attention
 from repro.kernels.ssd import ref as sref
 from repro.kernels.ssd.ssd_scan import ssd
+from repro.models.attention import KVCacheSpec, seed_kv_cache
 
 
 def _rand(key, *shape, dtype=jnp.float32):
@@ -49,40 +50,69 @@ def test_flash_attention_matches_ref(case, dtype):
 
 
 DECODE_CASES = [
-    # (b, h, kv, d, s_max, cache_len, window)
-    (2, 8, 2, 64, 300, 150, 0),
-    (1, 4, 4, 128, 512, 512, 0),
-    (3, 16, 2, 64, 256, 256, 128),   # rolling sliding-window cache
-    (2, 4, 1, 32, 1024, 700, 0),     # MQA, partially filled
-    (1, 8, 8, 64, 96, 1, 0),         # single valid entry
+    # (b, h, kv, d, s_max, cache_len, window, block_k); caches (B, KV, D, S_max)
+    (2, 8, 2, 64, 300, 150, 0, 128),      # s_max no multiple of 128: one block
+    (1, 4, 4, 128, 512, 512, 0, 128),
+    (3, 16, 2, 64, 256, 256, 128, 128),   # rolling sliding-window cache
+    (2, 4, 1, 32, 1024, 700, 0, 128),     # MQA, partially filled
+    (1, 8, 8, 64, 96, 1, 0, 128),         # single valid entry
+    (2, 16, 8, 64, 1280, 700, 0, 512),    # granite heads, blocks of 256
+    (2, 12, 2, 128, 1280, 1280, 0, 512),  # qwen2-vl heads, blocks of 256
+    (2, 16, 8, 64, 80, 50, 0, 512),       # one whole block of 80
+    (2, 12, 2, 128, 48, 48, 0, 512),      # one whole block of 48
 ]
 
 
 @pytest.mark.parametrize("case", DECODE_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_matches_ref(case, dtype):
-    b, h, kv, d, s_max, clen, window = case
+    b, h, kv, d, s_max, clen, window, block_k = case
     keys = jax.random.split(jax.random.key(hash(case) % 2**31), 3)
     q = _rand(keys[0], b, h, d, dtype=dtype)
-    kc = _rand(keys[1], b, s_max, kv, d, dtype=dtype)
-    vc = _rand(keys[2], b, s_max, kv, d, dtype=dtype)
+    kc = _rand(keys[1], b, kv, d, s_max, dtype=dtype)
+    vc = _rand(keys[2], b, kv, d, s_max, dtype=dtype)
     want = aref.decode_gqa(q, kc, vc, jnp.int32(clen), window=window)
     got = decode_attention(q, kc, vc, jnp.int32(clen), window=window,
-                           block_k=128, interpret=True)
+                           block_k=block_k, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), atol=_tol(dtype)
     )
 
 
 def test_decode_attention_per_example_lengths():
-    b, h, kv, d, s_max = 3, 4, 2, 32, 128
+    b, h, kv, d, s_max = 3, 4, 2, 32, 256
     keys = jax.random.split(jax.random.key(7), 3)
     q = _rand(keys[0], b, h, d)
-    kc = _rand(keys[1], b, s_max, kv, d)
-    vc = _rand(keys[2], b, s_max, kv, d)
-    lens = jnp.asarray([5, 77, 128], jnp.int32)
+    kc = _rand(keys[1], b, kv, d, s_max)
+    vc = _rand(keys[2], b, kv, d, s_max)
+    lens = jnp.asarray([5, 177, 256], jnp.int32)
     want = aref.decode_gqa(q, kc, vc, lens)
-    got = decode_attention(q, kc, vc, lens, block_k=64, interpret=True)
+    got = decode_attention(q, kc, vc, lens, block_k=128, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("s_max, block_k, want", [
+    (1280, 512, 256), (4096, 512, 512), (2048, 512, 512), (384, 512, 384),
+    (640, 256, 128), (128, 64, 128), (300, 512, 300), (80, 512, 80), (48, 512, 48),
+])
+def test_decode_block_divides_the_cache(s_max, block_k, want):
+    assert _block_len(s_max, block_k) == want
+
+
+@pytest.mark.parametrize("case", [(2, 8, 2, 64, 0), (1, 12, 2, 128, 0), (2, 4, 1, 32, 16)])
+def test_decode_reference_matches_last_prefill_row(case):
+    """The decode oracle over the cache layout equals full attention's
+    last query row over the projected (B, S, KV, D) keys and values."""
+    b, h, kv, d, window = case
+    s = 40
+    keys = jax.random.split(jax.random.key(hash(case) % 2**31), 3)
+    q = _rand(keys[0], b, s, h, d)
+    k = _rand(keys[1], b, s, kv, d)
+    v = _rand(keys[2], b, s, kv, d)
+    want = aref.mha(q, k, v, causal=True, window=window)[:, -1]
+    spec = KVCacheSpec(size=s)
+    got = aref.decode_gqa(q[:, -1], seed_kv_cache(k, spec, s), seed_kv_cache(v, spec, s),
+                          jnp.int32(s), window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 

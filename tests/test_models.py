@@ -10,6 +10,8 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
+from repro.kernels.attention import decode_attention as da
+from repro.kernels.attention import ops as attn_ops
 from repro.models.model import (
     INPUT_SHAPES, InputShape, build_model, concrete_inputs, shape_applicable,
 )
@@ -56,24 +58,7 @@ class TestSmoke:
         assert bool(jnp.isfinite(logits.astype(jnp.float32)).all())
 
     def test_decode_matches_prefill(self, arch):
-        cfg = get_config(arch, reduced=True)
-        api = build_model(cfg)
-        params = api.init(KEY, dtype=jnp.float32)
-        s, extra = 16, 4
-        batch = concrete_inputs(cfg, SMALL_PREFILL, KEY, dtype=jnp.float32)
-        max_len = s + extra
-        _, caches = api.prefill(params, batch, max_len)
-        toks = jax.random.randint(jax.random.key(2), (2, extra), 0, cfg.vocab_size)
-        last = None
-        for i in range(extra):
-            last, caches = api.decode_step(params, caches, toks[:, i], jnp.int32(s + i), max_len)
-        batch2 = dict(batch)
-        batch2["tokens"] = jnp.concatenate([batch["tokens"], toks], axis=1)
-        if "positions3" in batch2:
-            base = jnp.arange(s + extra, dtype=jnp.int32)[None, :, None]
-            batch2["positions3"] = jnp.broadcast_to(base, (2, s + extra, 3))
-        want, _ = api.prefill(params, batch2, max_len)
-        np.testing.assert_allclose(np.asarray(last), np.asarray(want), atol=1e-4)
+        _check_decode_matches_prefill(arch, max_len=16 + 4)
 
     def test_full_config_declares(self, arch):
         """FULL configs build decl trees + ShapeDtypeStructs w/o allocation."""
@@ -92,6 +77,53 @@ class TestSmoke:
         assert ok_500k == expect
         for s in ("train_4k", "prefill_32k", "decode_32k"):
             assert shape_applicable(cfg, INPUT_SHAPES[s])[0]
+
+
+def _check_decode_matches_prefill(arch: str, max_len: int):
+    """Prefill 16 tokens, decode 4 more into caches of `max_len`, and
+    compare the last logits with a prefill of all 20."""
+    cfg = get_config(arch, reduced=True)
+    api = build_model(cfg)
+    params = api.init(KEY, dtype=jnp.float32)
+    s, extra = 16, 4
+    batch = concrete_inputs(cfg, SMALL_PREFILL, KEY, dtype=jnp.float32)
+    _, caches = api.prefill(params, batch, max_len)
+    toks = jax.random.randint(jax.random.key(2), (2, extra), 0, cfg.vocab_size)
+    last = None
+    for i in range(extra):
+        last, caches = api.decode_step(params, caches, toks[:, i], jnp.int32(s + i), max_len)
+    batch2 = dict(batch)
+    batch2["tokens"] = jnp.concatenate([batch["tokens"], toks], axis=1)
+    if "positions3" in batch2:
+        base = jnp.arange(s + extra, dtype=jnp.int32)[None, :, None]
+        batch2["positions3"] = jnp.broadcast_to(base, (2, s + extra, 3))
+    want, _ = api.prefill(params, batch2, max_len)
+    np.testing.assert_allclose(np.asarray(last), np.asarray(want), atol=1e-4)
+
+
+@pytest.mark.parametrize("arch", [
+    "qwen2-vl-2b", "granite-moe-1b-a400m", "mixtral-8x7b", "recurrentgemma-9b",
+])
+def test_decode_matches_prefill_through_kernel(arch, monkeypatch):
+    """The same check with the attention of every decode step in the Pallas
+    decode kernel (interpreted): caches of 1,280 positions, no multiple of
+    the kernel's 512-position block, are read in blocks of 256 with no pad;
+    the rolling-window models keep a 16-position cache of their window."""
+    calls = []
+    kernel = da.decode_attention
+
+    def counted(q, k_cache, *args, **kw):
+        calls.append(k_cache.shape)
+        return kernel(q, k_cache, *args, **kw)
+
+    monkeypatch.setattr(attn_ops, "_default_impl", lambda: "pallas")
+    monkeypatch.setattr(da, "decode_attention", counted)
+    jax.clear_caches()  # no trace of the reference path may be reused
+    try:
+        _check_decode_matches_prefill(arch, max_len=1280)
+    finally:
+        jax.clear_caches()
+    assert calls and all(shape[-1] in (16, 1280) for shape in calls), calls
 
 
 class TestTrainingConvergence:

@@ -12,6 +12,9 @@ pytest-xdist every worker imports every test file.
 """
 from __future__ import annotations
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,9 +27,12 @@ from repro.core import workload
 from repro.core.agents import stack_fleets, synthetic_fleet
 from repro.core.simulator import SimConfig
 from repro.core.sweep import _stream_grid_jit
+from repro.kernels.attention import ops as attn_ops
 from repro.kernels.attention.decode_attention import decode_attention
 from repro.kernels.attention.flash_attention import flash_attention
 from repro.kernels.ssd.ssd_scan import ssd
+from repro.models.model import build_model
+from repro.models.params import abstract_params
 
 QWEN = get_config("qwen2-vl-2b")     # H=12, KV=2, D=128
 MAMBA = get_config("mamba2-370m")    # 32 SSD heads, P=64, N=128
@@ -76,14 +82,63 @@ def test_flash_attention_compiles_at_qwen2_vl_heads(one_chip, seq):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("s_max", [48, 4096])
+@pytest.mark.parametrize("s_max", [48, 1280, 4096])
 def test_decode_attention_compiles_at_qwen2_vl_heads(one_chip, s_max):
     h, kv, d = QWEN.num_heads, QWEN.num_kv_heads, QWEN.head_dim
     q = _sds((4, h, d), jnp.bfloat16, one_chip)
-    cache = _sds((4, s_max, kv, d), jnp.bfloat16, one_chip)
+    cache = _sds((4, kv, d, s_max), jnp.bfloat16, one_chip)
     clen = _sds((), jnp.int32, one_chip)
     txt = _compiled_text(decode_attention, q, cache, cache, clen)
     assert "tpu_custom_call" in txt
+
+
+# An HLO instruction: name, element type, dims, opcode.
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("arch", ["granite-moe-1b-a400m", "qwen2-vl-2b"])
+def test_decode_step_reads_the_kv_cache_where_it_lies(one_chip, arch, monkeypatch):
+    """The served decode step at published widths, 16 slots and 1,280-
+    position caches: the Pallas kernel reads each layer's K and V slab as
+    stored, so the compiled step holds no pad inside ``decode_attention``
+    and no copy or transpose (alone or fused) of a slab or of the stack."""
+    slots, max_len = 16, 1280
+    cfg = get_config(arch)
+    api = build_model(cfg)
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), tree
+    )
+    args = (
+        place(api.abstract()),
+        place(abstract_params(api.cache_decls(slots, max_len))),
+        _sds((slots,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip),
+    )
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    jax.clear_caches()  # no trace of the reference path may be reused
+    try:
+        txt = _compiled_text(
+            lambda p, c, t, pos: api.decode_step(p, c, t, pos, max_len), *args
+        )
+    finally:
+        jax.clear_caches()
+    assert "tpu_custom_call" in txt
+    slab = slots * cfg.num_kv_heads * cfg.head_dim * max_len
+    moved = []
+    for line in txt.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, _, dims, op = m.groups()
+        size = math.prod(int(x) for x in dims.split(",") if x)
+        relayout = op in ("copy", "transpose") or (
+            op == "fusion" and ("copy" in name or "transpose" in name)
+        )
+        if (op == "pad" and "decode_attention" in line) or (
+            relayout and size % slab == 0
+        ):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
 
 
 @pytest.mark.parametrize("seq", [128, 512])
